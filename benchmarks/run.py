@@ -26,6 +26,7 @@ import router_bench
 import scenarios as scenarios_suite
 import trace_replay
 from common import preset_from_argv
+from repro.compile_cache import enable_compile_cache
 
 # The suite registry: (name, entry point taking a Preset, one-line help).
 # --only= names, --help text, and the summary CSV all come from here.
@@ -151,12 +152,14 @@ def _headline(name, out):
     return ""
 
 
-def main() -> None:
-    """Parse flags, run the selected suites, print the CSV summary."""
+def main() -> int:
+    """Parse flags, run the selected suites, print the CSV summary.
+    Returns 1 if any suite failed (the others still run), else 0."""
     if "--help" in sys.argv[1:] or "-h" in sys.argv[1:]:
         print(usage())
-        return
+        return 0
     preset = preset_from_argv()
+    enable_compile_cache()
     print(f"[benchmarks] preset={preset.name} M={preset.cluster.M} "
           f"K={preset.cluster.K} T={preset.cfg.T}")
     only = [a.split("=", 1)[1] for a in sys.argv[1:]
@@ -172,18 +175,24 @@ def main() -> None:
                              f"see --help")
         suites = [(n, fn) for n, fn in suites if n in wanted]
     summary = []
+    failed = []
     for name, fn in suites:
         t0 = time.time()
         try:
             out = fn(preset)
             summary.append((name, time.time() - t0, _headline(name, out)))
-        except Exception as e:  # keep the harness running
+        except Exception as e:  # run the other suites, then fail below
+            failed.append(name)
             summary.append((name, time.time() - t0, f"FAILED: {e}"))
             print(f"[benchmarks] {name} FAILED: {e}", file=sys.stderr)
     print("\nname,seconds,headline")
     for name, dt, head in summary:
         print(f"{name},{dt:.1f},{head}")
+    if failed:
+        print(f"[benchmarks] failed suites: {','.join(failed)}",
+              file=sys.stderr)
+    return 1 if failed else 0
 
 
 if __name__ == "__main__":
-    main()
+    sys.exit(main())

@@ -6,9 +6,9 @@ router must trade locality against load.  We run the SAME workload under
 three routing policies and compare completion time and scheduler cost:
 
     pod   — Balanced-Pandas-Pod (paper's proposal): 3 locals + d=8 samples,
-            O(1) probes, Pallas pod_route kernel
+            O(1) probes, route_commit pod variant (Pallas)
     full  — Balanced-Pandas: argmin over all M replicas, O(M) probes,
-            Pallas weighted_argmin kernel
+            route_commit full variant (Pallas)
     rand  — uniform random (locality-blind control)
 
 Token generation is real (jit'd decode_step on a small llama-family model).

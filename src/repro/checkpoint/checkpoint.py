@@ -77,9 +77,7 @@ _PENDING: list[threading.Thread] = []
 
 
 def _tree_flatten_with_paths(tree):
-    # jax.tree.flatten_with_path only landed in jax 0.4.x-late; the
-    # tree_util spelling works across every version this repo supports.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(tree)
+    flat, treedef = jax.tree.flatten_with_path(tree)
     paths = ["/".join(str(k) for k in path) for path, _ in flat]
     leaves = [leaf for _, leaf in flat]
     return paths, leaves, treedef

@@ -36,9 +36,10 @@ Routing modes:
                include arrival b's commit (a W-delta accumulator in VMEM).
                This preserves the paper's per-arrival semantics — a burst
                spreads instead of herding onto one snapshot argmin — at
-               one launch per slot; it is the same [M, 3]-rate MXU path
+               one launch per slot; it is the same [M, 3]-rate kernel
                the production PodRouter runs, traced inline into the
-               jit'd step (interpret mode off-TPU).  Exact score ties
+               jit'd step (Mosaic on TPU, the interpreter on CPU).
+               Exact score ties
                resolve by locality class (LOCAL < RACK < REMOTE), then
                lowest server index / candidate slot — an exact integer
                rank lane in-kernel, valid at any workload magnitude —
@@ -1142,6 +1143,24 @@ def _sweep_cells(keys, lam, scen, *, algo, cluster, rates, cfg, pod, a_max,
     return jax.vmap(per_scen)(lam, scen)
 
 
+@functools.partial(
+    jax.jit,
+    static_argnames=("mesh", "algo", "cluster", "rates", "cfg", "pod",
+                     "a_max", "tcfg"))
+def _sweep_sharded(keys, lam, scen, *, mesh, algo, cluster, rates, cfg, pod,
+                   a_max, tcfg):
+    """``_sweep_cells`` with the scenario axis shard_mapped over ``mesh``.
+    Jitted on the static grid description, so a repeated sweep over the
+    same mesh and shapes reuses its program instead of compiling again."""
+    from jax.sharding import PartitionSpec as P
+    kw = dict(algo=algo, cluster=cluster, rates=rates, cfg=cfg, pod=pod,
+              a_max=a_max, tcfg=tcfg)
+    return jax.shard_map(
+        lambda k, l, sc: _sweep_cells(k, l, sc, **kw), mesh=mesh,
+        in_specs=(P(), P("scen"), P("scen")), out_specs=P("scen"),
+        check_vma=False)(keys, lam, scen)
+
+
 def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
                    n_seeds: int, cfg: SimConfig = SimConfig(),
                    pod: Optional[PodSpec] = None, seed0: int = 0,
@@ -1183,8 +1202,7 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
     S = lam.shape[0]
     D = min(len(devs), S)
     if D > 1:
-        from jax.experimental.shard_map import shard_map
-        from jax.sharding import Mesh, PartitionSpec as P
+        from jax.sharding import Mesh
 
         pad_s = (-S) % D
         if pad_s:
@@ -1194,11 +1212,7 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
             scen = jax.tree_util.tree_map(rep, scen)
             lam = rep(lam)
         mesh = Mesh(_np.asarray(devs[:D]), ("scen",))
-        fn = shard_map(
-            lambda k, l, sc: _sweep_cells(k, l, sc, **kw), mesh=mesh,
-            in_specs=(P(), P("scen"), P("scen")), out_specs=P("scen"),
-            check_rep=False)
-        sums, tele = fn(keys, lam, scen)
+        sums, tele = _sweep_sharded(keys, lam, scen, mesh=mesh, **kw)
         if pad_s:
             drop = lambda x: x[:S]
             sums = jax.tree_util.tree_map(drop, sums)

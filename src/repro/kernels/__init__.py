@@ -12,8 +12,9 @@ weighted_argmin — O(M) Balanced-Pandas snapshot routing (the baseline the
                   recompute.  These three remain the per-arrival
                   (sequential route_mode) building blocks.
 
-ref.py holds the pure-jnp oracles; ops.py the jit'd wrappers.  ``interpret``
-auto-selects per backend (interpreter off-TPU, Mosaic on TPU).
+ref.py holds the pure-jnp oracles.  ``interpret=None`` resolves per
+backend (``invrates.resolve_interpret``): Mosaic on TPU, the interpreter on
+CPU, an error on anything else.
 
 All kernels take their inverse-rate operand as either the homogeneous
 ``[3]`` vector or a per-server ``[M, 3]`` matrix (heterogeneous fleets);
@@ -21,7 +22,10 @@ zero-rate servers carry ``+inf`` inverse rates and are masked to ``+inf``
 scores after the multiply (invrates.py documents the finite encoding).
 """
 from . import ref
-from .ops import pod_route, queue_update, route_commit, weighted_argmin
+from .pod_route import pod_route
+from .queue_update import queue_update
+from .route_commit import route_commit
+from .weighted_argmin import weighted_argmin
 
 __all__ = ["ref", "pod_route", "queue_update", "route_commit",
            "weighted_argmin"]

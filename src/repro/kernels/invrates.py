@@ -37,12 +37,21 @@ FLAG_BASE = 4
 
 
 def resolve_interpret(interpret: Optional[bool]) -> bool:
-    """Shared ``interpret`` auto-default for every kernel in this package:
-    None -> Pallas interpreter everywhere except a real TPU backend (where
-    the same call compiles to Mosaic).  Explicit True/False pass through."""
-    if interpret is None:
-        return jax.default_backend() != "tpu"
-    return interpret
+    """Shared ``interpret`` auto-default for every kernel in this package.
+
+    None -> Mosaic on a TPU backend, the Pallas interpreter on the CPU
+    backend (tests), and an error on any other backend: a kernel never
+    falls back to the interpreter silently on an accelerator.  Explicit
+    True/False pass through."""
+    if interpret is not None:
+        return interpret
+    backend = jax.default_backend()
+    if backend == "tpu":
+        return False
+    if backend == "cpu":
+        return True
+    raise RuntimeError(f"no Pallas path for backend {backend!r}: the "
+                       "kernels compile for TPU and interpret on CPU")
 
 
 def as_matrix(inv_rates: jnp.ndarray, M: int) -> jnp.ndarray:

@@ -112,6 +112,14 @@ def _finite_dead(inv_rates: jnp.ndarray, M: int):
 _RANK_BIG = jnp.int32(2**30)
 
 
+def _w0(Q: jnp.ndarray, finite: jnp.ndarray) -> jnp.ndarray:
+    """W0 [M] = Q[:, 0] * r0 + Q[:, 1] * r1 + Q[:, 2] * r2, added in that
+    order (route_commit sums in the same order, so W matches bit for bit
+    on every backend)."""
+    p = Q.astype(jnp.float32) * finite
+    return (p[:, 0] + p[:, 1]) + p[:, 2]
+
+
 def route_commit_ref(Q: jnp.ndarray, valid: jnp.ndarray,
                      inv_rates: jnp.ndarray, *,
                      cls: Optional[jnp.ndarray] = None,
@@ -140,7 +148,7 @@ def route_commit_ref(Q: jnp.ndarray, valid: jnp.ndarray,
     """
     M = Q.shape[0]
     finite, dead = _finite_dead(inv_rates, M)
-    W0 = (Q.astype(jnp.float32) * finite).sum(-1)
+    W0 = _w0(Q, finite)
 
     if cls is not None:
         m = jnp.arange(M, dtype=jnp.int32)
@@ -205,7 +213,7 @@ def route_commit_wseq(Q: jnp.ndarray, sel: jnp.ndarray, sel_cls: jnp.ndarray,
     """
     M = Q.shape[0]
     finite, _ = _finite_dead(inv_rates, M)
-    W0 = (Q.astype(jnp.float32) * finite).sum(-1)
+    W0 = _w0(Q, finite)
     m = jnp.arange(M, dtype=jnp.int32)
 
     def step(dw, xs):

@@ -42,18 +42,30 @@ yields a deterministic pick (lowest class, then priority/index, valid
 slots preferred) and the W commit is 0 (dead entries carry finite rate 0
 in the encoding).
 
-TPU mapping: one launch, whole operands VMEM-resident (the wrapper pads M
-to 128 lanes, B and C to multiples of 8).  The heavy work — the initial
-workload recompute ``W0 = sum(Q * inv)``, the pod candidate gather
-(one-hot matmul, same formulation as pod_route), and the final Q scatter
-(``one_hot(sel)^T @ one_hot(cls)`` on the MXU, same as queue_update) — is
-batch-parallel; only the light argmin + rank-1 W-delta update runs in the
-sequential ``fori_loop`` over arrivals.  VMEM high-water is the pod
-variant's one-hot gather, ~B*C*Mp*4 bytes (B=64, C=16, M=8192 -> 32 MiB;
-tile the batch on the host above that).
+TPU mapping: one launch, whole operands VMEM-resident.  Servers sit on
+lanes: Q and the inverse rates enter as [8, Mp] (classes on sublanes, M
+padded to 128 lanes), W leaves as a [1, Mp] row, and the per-arrival
+outputs as [Bp, 1] columns (B and C padded to multiples of 8), so the
+kernel needs no reshape at all.  Batch-parallel work: the workload
+recompute ``W0 = Q0*r0 + Q1*r1 + Q2*r2``, the pod variant's candidate
+gathers (one [Bp, Mp] one-hot per candidate slot, lane-reduced with a
+select, so the gather is exact), and the final Q commit (a masked
+integer column sum per class).  The sequential ``while_loop`` over
+arrivals does only the argmin and the rank-1 W-delta update: the full
+variant reads arrival b's class row from VMEM (O(M) per step); the pod
+variant keeps the delta mirrored at every candidate ([Bp, Cp]).
 
-``interpret=None`` (default) auto-selects the Pallas interpreter off-TPU;
-on a TPU backend the same call compiles to Mosaic.
+Limits (compiled for a described v5e, ``tests/test_tpu_compile.py``):
+the tie-rank lane needs ``4 * Mp**2 < 2**30``, i.e. M <= 16256.  Below
+that, VMEM bounds the full variant: it holds ``cls`` [Bp, Mp] int32 and
+a few [Bp, Mp] temporaries of the vectorized tail inside the default
+scoped VMEM (16 MiB on v5e).  Both variants compile at M=5000, B=96
+(about 2 s each) and at M=8192, B=96; the full variant at M=16000, B=96
+runs out of VMEM, the pod variant compiles.  There is no host-side
+batch tiling: a caller above the limit gets the compiler's error.
+
+``interpret=None`` (default) resolves through ``resolve_interpret``:
+Mosaic on a TPU backend, the Pallas interpreter on the CPU backend.
 Oracle: ref.route_commit_ref (exact sequential-commit semantics).
 """
 from __future__ import annotations
@@ -77,57 +89,83 @@ def _class_select(sel_key, per_class):
                      jnp.where(sel_key == 1, per_class[1], per_class[2]))
 
 
-def _commit_q(q, sel_v, cls_v, mask, b_pad, m_pad):
-    """dQ = one_hot(sel)^T @ one_hot(cls) over accepted arrivals (the
-    queue_update formulation — collision-free on the MXU)."""
+def _rows(ref, base):
+    """The three class rows ``ref[base + k]`` of a [8, Mp] operand."""
+    return [ref[base + k:base + k + 1, :] for k in range(3)]
+
+
+def _workload(q_ref, invm_ref):
+    """W0 [1, Mp] = sum_k Q[k] * inv[k], summed in class order (the
+    oracle's order, so W is bit-equal to ref.route_commit_ref)."""
+    q = [r.astype(jnp.float32) for r in _rows(q_ref, 0)]
+    r = _rows(invm_ref, 0)
+    return (q[0] * r[0] + q[1] * r[1]) + q[2] * r[2]
+
+
+def _pick_row(row, x, fill):
+    """Row ``b`` of a [Bp, N] block as [1, N] (``row``: [Bp, 1] mask)."""
+    return jnp.sum(jnp.where(row, x, fill), axis=0, keepdims=True)
+
+
+def _write_outputs(q_ref, mask, sel_v, cls_v, val_v, w,
+                   qout_ref, wout_ref, sel_ref, selcls_ref, val_ref,
+                   *, m_pad: int, b_pad: int):
+    """Commit the accepted arrivals into Q and store every output.
+
+    dQ[k, m] counts accepted arrivals routed to server m in class k: a
+    masked integer column sum per class (exact, no MXU)."""
     iota_bm = jax.lax.broadcasted_iota(jnp.int32, (b_pad, m_pad), 1)
-    oh_sel = ((iota_bm == sel_v.reshape(b_pad, 1))
-              & (mask.reshape(b_pad, 1) > 0)).astype(jnp.float32)
-    iota_bc = jax.lax.broadcasted_iota(jnp.int32, (b_pad, 8), 1)
-    oh_cls = (iota_bc == cls_v.reshape(b_pad, 1)).astype(jnp.float32)
-    dq = jax.lax.dot_general(oh_sel, oh_cls, (((0,), (0,)), ((), ())),
-                             preferred_element_type=jnp.float32)
-    return (q + dq).astype(jnp.int32)
+    hit = (iota_bm == sel_v) & (mask > 0)                     # [Bp, Mp]
+    qout_ref[...] = q_ref[...]
+    for k in range(3):
+        dq = jnp.sum(jnp.where(hit & (cls_v == k), 1, 0), axis=0,
+                     keepdims=True)
+        qout_ref[k:k + 1, :] = q_ref[k:k + 1, :] + dq
+    wout_ref[...] = w
+    sel_ref[...] = sel_v
+    selcls_ref[...] = cls_v
+    val_ref[...] = val_v
+
+
+def _loop_init(m_pad, b_pad):
+    return (jnp.int32(0),
+            jnp.zeros((1, m_pad), jnp.float32),
+            jnp.zeros((b_pad, 1), jnp.int32),
+            jnp.zeros((b_pad, 1), jnp.int32),
+            jnp.zeros((b_pad, 1), jnp.float32))
 
 
 def _kernel_full(q_ref, cls_ref, mask_ref, invm_ref, prio_ref,
                  qout_ref, wout_ref, sel_ref, selcls_ref, val_ref,
                  *, m_pad: int, b_pad: int):
-    q = q_ref[...].astype(jnp.float32)           # [Mp, 8] (3 cols used)
-    cls = cls_ref[...]                           # [Bp, Mp] (pad rows: 3)
-    mask = mask_ref[...]                         # [1, Bp]  (commit gate)
-    ir = invm_ref[...]                           # [Mp, 8] (see invrates)
-    prio = prio_ref[...]                         # [1, Mp] tie priority < Mp
-
-    # fused workload recompute: flag cols multiply the zero pad cols of q
-    w0 = jnp.sum(q * ir, axis=1)[None, :]        # [1, Mp]
-    rates = [ir[:, k][None, :] for k in range(3)]
-    flags = [ir[:, FLAG_BASE + k][None, :] for k in range(3)]
-    factor = _class_select(cls, rates)           # [Bp, Mp] finite 1/rate
-    elig = ((cls < 3) & (_class_select(cls, flags) == 0.0)).astype(jnp.int32)
+    # layout: servers on lanes.  q/invm: [8, Mp] (rows 0..2 = classes,
+    # invm rows 4..6 = dead flags); cls: [Bp, Mp] (pad rows/lanes: 3);
+    # mask: [Bp, 1] commit gate; prio: [1, Mp] tie priority < Mp.
+    mask = mask_ref[...]
+    prio = prio_ref[...]
+    w0 = _workload(q_ref, invm_ref)                          # [1, Mp]
+    rates = _rows(invm_ref, 0)
+    flags = _rows(invm_ref, FLAG_BASE)
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (b_pad, 1), 0)
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (1, m_pad), 1)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, b_pad), 1)
 
     # arrivals past the last committing (valid) one never change dW — the
     # sequential loop only needs to run that far; everything after is one
     # vectorized tail pass against the final dW.  Typical batches are
     # Poisson draws far below the a_max padding, so this cuts the
     # sequential trip count from Bp to ~E[arrivals].
-    n_proc = jnp.max(jnp.where(mask > 0, iota_b + 1, 0))
+    n_proc = jnp.max(jnp.where(mask > 0, rows + 1, 0))
 
     def cond(carry):
         return carry[0] < n_proc
 
     def body(carry):
         b, dw, sel_v, cls_v, val_v = carry
-        row = rows == b
-        # mask-reduce row b out of the batch blocks (static shapes: no
-        # dynamic slicing inside the loop)
-        cls_b = jnp.sum(jnp.where(row, cls, 0), axis=0, keepdims=True)
-        fac_b = jnp.sum(jnp.where(row, factor, 0.0), axis=0, keepdims=True)
-        ok_b = jnp.sum(jnp.where(row, elig, 0), axis=0, keepdims=True) > 0
+        # arrival b's class row, read straight from VMEM: O(M) per step
+        cls_b = cls_ref[pl.ds(b, 1), :]                      # [1, Mp]
+        fac_b = _class_select(cls_b, rates)
+        ok_b = (cls_b < 3) & (_class_select(cls_b, flags) == 0.0)
         # arrival b scores against W0 + the commits of arrivals 0..b-1
         scores = jnp.where(ok_b, (w0 + dw) * fac_b, jnp.inf)
         best = jnp.min(scores)
@@ -138,100 +176,100 @@ def _kernel_full(q_ref, cls_ref, mask_ref, invm_ref, prio_ref,
         rb = jnp.min(rank)
         sel = rb % m_pad
         scls = rb // (m_pad * m_pad)
-        accept = jnp.sum(jnp.where(iota_b == b, mask, 0)) > 0
+        onb = rows == b
+        accept = jnp.sum(jnp.where(onb, mask, 0)) > 0
         # W-delta accumulator: the committed task adds 1/rate at (sel, cls)
         # (0 for a dead server — finite encoding carries 0 there)
         dw = dw + jnp.where((iota_m == sel) & accept, fac_b, 0.0)
-        onb = iota_b == b
         return (b + 1, dw, jnp.where(onb, sel, sel_v),
                 jnp.where(onb, scls, cls_v), jnp.where(onb, best, val_v))
 
-    init = (jnp.int32(0),
-            jnp.zeros((1, m_pad), jnp.float32),
-            jnp.zeros((1, b_pad), jnp.int32),
-            jnp.zeros((1, b_pad), jnp.int32),
-            jnp.zeros((1, b_pad), jnp.float32))
-    _, dw, sel_v, cls_v, val_v = jax.lax.while_loop(cond, body, init)
+    _, dw, sel_v, cls_v, val_v = jax.lax.while_loop(
+        cond, body, _loop_init(m_pad, b_pad))
 
     # vectorized tail: arrivals b >= n_proc (all invalid) score against
     # the final dW — identical semantics to running the loop to Bp
-    scores_t = jnp.where(elig > 0, (w0 + dw) * factor, jnp.inf)  # [Bp, Mp]
+    cls = cls_ref[...]
+    elig = (cls < 3) & (_class_select(cls, flags) == 0.0)
+    scores_t = jnp.where(elig, (w0 + dw) * _class_select(cls, rates),
+                         jnp.inf)                                # [Bp, Mp]
     best_t = jnp.min(scores_t, axis=1, keepdims=True)            # [Bp, 1]
     rank_t = jnp.where(scores_t == best_t,
                        (cls * m_pad + prio) * m_pad + iota_m, _BIG)
     rb_t = jnp.min(rank_t, axis=1, keepdims=True)
-    done = iota_b < n_proc
-    sel_v = jnp.where(done, sel_v, (rb_t % m_pad).reshape(1, b_pad))
-    cls_v = jnp.where(done, cls_v,
-                      (rb_t // (m_pad * m_pad)).reshape(1, b_pad))
-    val_v = jnp.where(done, val_v, best_t.reshape(1, b_pad))
+    done = rows < n_proc
+    sel_v = jnp.where(done, sel_v, rb_t % m_pad)
+    cls_v = jnp.where(done, cls_v, rb_t // (m_pad * m_pad))
+    val_v = jnp.where(done, val_v, best_t)
 
-    qout_ref[...] = _commit_q(q, sel_v, cls_v, mask, b_pad, m_pad)
-    wout_ref[...] = (w0 + dw).reshape(m_pad, 1)
-    sel_ref[...] = sel_v
-    selcls_ref[...] = cls_v
-    val_ref[...] = val_v
+    _write_outputs(q_ref, mask, sel_v, cls_v, val_v, w0 + dw,
+                   qout_ref, wout_ref, sel_ref, selcls_ref, val_ref,
+                   m_pad=m_pad, b_pad=b_pad)
 
 
 def _kernel_pod(q_ref, idx_ref, cls_ref, cval_ref, mask_ref, invm_ref,
                 qout_ref, wout_ref, sel_ref, selcls_ref, val_ref,
                 *, m_pad: int, c_pad: int, b_pad: int, homogeneous: bool):
-    q = q_ref[...].astype(jnp.float32)           # [Mp, 8]
     cand = idx_ref[...]                          # [Bp, Cp]
     ccls = cls_ref[...]                          # [Bp, Cp] (pad: 3)
     cval = cval_ref[...]                         # [Bp, Cp] int 0/1
-    mask = mask_ref[...]                         # [1, Bp]
-    ir = invm_ref[...]                           # [Mp, 8]
-
-    w0 = jnp.sum(q * ir, axis=1)[None, :]        # [1, Mp]
-    # candidate one-hot (the pod_route formulation): serves the workload
-    # gathers (w0 + dW, fused into one dot each) and — heterogeneous
-    # fleets only — the per-candidate rate/flag gather.
-    flat = cand.reshape(b_pad * c_pad, 1)
-    iota_mm = jax.lax.broadcasted_iota(jnp.int32, (b_pad * c_pad, m_pad), 1)
-    onehot = (iota_mm == flat).astype(jnp.float32)           # [B*C, Mp]
-    if homogeneous:
-        # every row of ir is identical: the per-candidate rate is a pure
-        # function of the class — no [B*C, Mp] x [Mp, 8] gather matmul
-        factor = _class_select(ccls, [ir[0, 0], ir[0, 1], ir[0, 2]])
-        dead = _class_select(ccls, [ir[0, FLAG_BASE], ir[0, FLAG_BASE + 1],
-                                    ir[0, FLAG_BASE + 2]])
-    else:
-        irc = jax.lax.dot_general(onehot, ir, (((1,), (0,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        col = lambda k: irc[:, k].reshape(b_pad, c_pad)
-        factor = _class_select(ccls, [col(0), col(1), col(2)])
-        dead = _class_select(ccls, [col(FLAG_BASE), col(FLAG_BASE + 1),
-                                    col(FLAG_BASE + 2)])
-    elig = ((cval > 0) & (ccls < 3) & (dead == 0.0)).astype(jnp.int32)
+    mask = mask_ref[...]                         # [Bp, 1]
+    w0 = _workload(q_ref, invm_ref)              # [1, Mp]
 
     rows = jax.lax.broadcasted_iota(jnp.int32, (b_pad, 1), 0)
     iota_c = jax.lax.broadcasted_iota(jnp.int32, (1, c_pad), 1)
     iota_m = jax.lax.broadcasted_iota(jnp.int32, (1, m_pad), 1)
-    iota_b = jax.lax.broadcasted_iota(jnp.int32, (1, b_pad), 1)
-    iota_cm = jax.lax.broadcasted_iota(jnp.int32, (c_pad, m_pad), 1)
+    iota_bc = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c_pad), 1)
+    iota_bm = jax.lax.broadcasted_iota(jnp.int32, (b_pad, m_pad), 1)
+
+    # candidate gathers, one slot column at a time: a [Bp, Mp] one-hot
+    # against candidate column c, lane-reduced to [Bp, 1] and placed at
+    # lane c.  Select + sum is an exact gather (every other term is 0).
+    if homogeneous:
+        # every server has the same rates: the per-candidate rate is a
+        # pure function of the class (scalars from lane 0)
+        lane0 = lambda base: [x[:, 0:1] for x in _rows(invm_ref, base)]
+        factor = _class_select(ccls, lane0(0))
+        dead = _class_select(ccls, lane0(FLAG_BASE))
+        srcs = [w0]
+    else:
+        srcs = [w0] + _rows(invm_ref, 0) + _rows(invm_ref, FLAG_BASE)
+
+    def gather(c, acc):
+        at_c = iota_bc == c
+        oh = iota_bm == jnp.sum(jnp.where(at_c, cand, 0), axis=1,
+                                keepdims=True)                  # [Bp, Mp]
+        return tuple(
+            jnp.where(at_c, jnp.sum(jnp.where(oh, src, 0.0), axis=1,
+                                    keepdims=True), a)
+            for src, a in zip(srcs, acc))
+
+    zero_bc = jnp.zeros((b_pad, c_pad), jnp.float32)
+    g = jax.lax.fori_loop(0, c_pad, gather, (zero_bc,) * len(srcs))
+    w0c = g[0]                                   # W0[cand]  [Bp, Cp]
+    if not homogeneous:
+        factor = _class_select(ccls, list(g[1:4]))
+        dead = _class_select(ccls, list(g[4:7]))
+    elig = ((cval > 0) & (ccls < 3) & (dead == 0.0)).astype(jnp.int32)
 
     # sequential work stops after the last valid arrival (see _kernel_full)
-    n_proc = jnp.max(jnp.where(mask > 0, iota_b + 1, 0))
+    n_proc = jnp.max(jnp.where(mask > 0, rows + 1, 0))
 
     def cond(carry):
-        return carry[0] < n_proc
+        return carry[0][0] < n_proc
 
     def body(carry):
-        b, dw, sel_v, cls_v, val_v = carry       # dw: [1, Mp]
+        # dwc [Bp, Cp] mirrors dw at every candidate: dwc[b, c] is
+        # dw[cand[b, c]] built from the same additions in the same order,
+        # so W0[cand] + dwc is bit-equal to (W0 + dW)[cand]
+        (b, dw, sel_v, cls_v, val_v), dwc = carry
         row = rows == b
-        ccls_b = jnp.sum(jnp.where(row, ccls, 0), axis=0, keepdims=True)
-        cand_b = jnp.sum(jnp.where(row, cand, 0), axis=0, keepdims=True)
-        cval_b = jnp.sum(jnp.where(row, cval, 0), axis=0, keepdims=True)
-        fac_b = jnp.sum(jnp.where(row, factor, 0.0), axis=0, keepdims=True)
-        ok_b = jnp.sum(jnp.where(row, elig, 0), axis=0, keepdims=True) > 0
-        # row-b candidate view of W0 + the intra-batch commits so far: one
-        # small [Cp, Mp] one-hot gather (NOT the whole [B*C, Mp] block per
-        # step, and w0 rides along in the same dot)
-        oh_b = (iota_cm == cand_b.reshape(c_pad, 1)).astype(jnp.float32)
-        wc_b = jax.lax.dot_general(
-            oh_b, w0 + dw, (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32).reshape(1, c_pad)
+        ccls_b = _pick_row(row, ccls, 0)
+        cand_b = _pick_row(row, cand, 0)
+        cval_b = _pick_row(row, cval, 0)
+        fac_b = _pick_row(row, factor, 0.0)
+        ok_b = _pick_row(row, elig, 0) > 0
+        wc_b = _pick_row(row, w0c, 0.0) + _pick_row(row, dwc, 0.0)
         scores = jnp.where(ok_b, wc_b * fac_b, jnp.inf)
         best = jnp.min(scores)
         # class lane, then slot order; invalid slots only as a last resort
@@ -243,27 +281,20 @@ def _kernel_pod(q_ref, idx_ref, cls_ref, cval_ref, mask_ref, invm_ref,
         sel = jnp.sum(jnp.where(slot_oh, cand_b, 0))
         scls = jnp.sum(jnp.where(slot_oh, ccls_b, 0))
         amt = jnp.sum(jnp.where(slot_oh, fac_b, 0.0))
-        accept = jnp.sum(jnp.where(iota_b == b, mask, 0)) > 0
+        accept = jnp.sum(jnp.where(row, mask, 0)) > 0
         dw = dw + jnp.where((iota_m == sel) & accept, amt, 0.0)
-        onb = iota_b == b
-        return (b + 1, dw, jnp.where(onb, sel, sel_v),
-                jnp.where(onb, scls, cls_v), jnp.where(onb, best, val_v))
+        dwc = dwc + jnp.where((cand == sel) & accept, amt, 0.0)
+        return ((b + 1, dw, jnp.where(row, sel, sel_v),
+                 jnp.where(row, scls, cls_v), jnp.where(row, best, val_v)),
+                dwc)
 
-    init = (jnp.int32(0),
-            jnp.zeros((1, m_pad), jnp.float32),
-            jnp.zeros((1, b_pad), jnp.int32),
-            jnp.zeros((1, b_pad), jnp.int32),
-            jnp.zeros((1, b_pad), jnp.float32))
-    _, dw, sel_v, cls_v, val_v = jax.lax.while_loop(cond, body, init)
+    (_, dw, sel_v, cls_v, val_v), dwc = jax.lax.while_loop(
+        cond, body, (_loop_init(m_pad, b_pad), zero_bc))
 
     # vectorized tail for arrivals past the last valid one: all score
-    # against the final dW (one whole-batch gather via the big one-hot)
-    wc = jax.lax.dot_general(
-        onehot, w0 + dw, (((1,), (1,)), ((), ())),
-        preferred_element_type=jnp.float32).reshape(b_pad, c_pad)
-    scores_t = jnp.where(elig > 0, wc * factor, jnp.inf)
+    # against the final dW
+    scores_t = jnp.where(elig > 0, (w0c + dwc) * factor, jnp.inf)
     best_t = jnp.min(scores_t, axis=1, keepdims=True)            # [Bp, 1]
-    iota_bc = jax.lax.broadcasted_iota(jnp.int32, (b_pad, c_pad), 1)
     rank_t = jnp.where(scores_t == best_t,
                        ccls * c_pad + iota_bc + (1 - cval) * 4 * c_pad,
                        _BIG)
@@ -271,20 +302,20 @@ def _kernel_pod(q_ref, idx_ref, cls_ref, cval_ref, mask_ref, invm_ref,
     slot_oh_t = iota_bc == slot_t
     sel_t = jnp.sum(jnp.where(slot_oh_t, cand, 0), axis=1, keepdims=True)
     scls_t = jnp.sum(jnp.where(slot_oh_t, ccls, 0), axis=1, keepdims=True)
-    done = iota_b < n_proc
-    sel_v = jnp.where(done, sel_v, sel_t.reshape(1, b_pad))
-    cls_v = jnp.where(done, cls_v, scls_t.reshape(1, b_pad))
-    val_v = jnp.where(done, val_v, best_t.reshape(1, b_pad))
+    done = rows < n_proc
+    sel_v = jnp.where(done, sel_v, sel_t)
+    cls_v = jnp.where(done, cls_v, scls_t)
+    val_v = jnp.where(done, val_v, best_t)
 
-    qout_ref[...] = _commit_q(q, sel_v, cls_v, mask, b_pad, m_pad)
-    wout_ref[...] = (w0 + dw).reshape(m_pad, 1)
-    sel_ref[...] = sel_v
-    selcls_ref[...] = cls_v
-    val_ref[...] = val_v
+    _write_outputs(q_ref, mask, sel_v, cls_v, val_v, w0 + dw,
+                   qout_ref, wout_ref, sel_ref, selcls_ref, val_ref,
+                   m_pad=m_pad, b_pad=b_pad)
 
 
-def _pad_q(Q, Mp):
-    return jnp.pad(Q.astype(jnp.int32), ((0, Mp - Q.shape[0]), (0, 5)))
+def _server_rows(x, Mp):
+    """[M, k] per-server operand -> [8, Mp] with servers on lanes."""
+    M, k = x.shape
+    return jnp.pad(x.T, ((0, WIDTH - k), (0, Mp - M)))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -326,16 +357,16 @@ def route_commit(Q: jnp.ndarray, valid: jnp.ndarray, inv_rates: jnp.ndarray,
     lane = LANE if not interp else 8
     Mp = max(8, -(-M // lane) * lane)
     assert 4 * Mp * Mp < _BIG, f"M={M}: tie-rank lane overflows int32"
-    q_p = _pad_q(Q, Mp)
-    invm = jnp.pad(encode(inv_rates, M), ((0, Mp - M), (0, 0)))  # [Mp, 8]
+    q_p = _server_rows(Q.astype(jnp.int32), Mp)                # [8, Mp]
+    invm = _server_rows(encode(inv_rates, M), Mp)              # [8, Mp]
+    B = (cls if cls is not None else cand_idx).shape[0]
+    Bp = max(8, -(-B // 8) * 8)
+    mask_p = jnp.pad(valid.astype(jnp.int32), (0, Bp - B))[:, None]
 
     if cls is not None:
         assert cand_idx is None, "pass cls OR cand_idx, not both"
-        B = cls.shape[0]
-        Bp = max(8, -(-B // 8) * 8)
         cls_p = jnp.pad(cls.astype(jnp.int32), ((0, Bp - B), (0, Mp - M)),
                         constant_values=3)
-        mask_p = jnp.pad(valid.astype(jnp.int32), (0, Bp - B))[None, :]
         prio_p = jnp.arange(Mp, dtype=jnp.int32)   # pad lanes keep < Mp
         if prio is not None:
             prio_p = prio_p.at[:M].set(prio.astype(jnp.int32))
@@ -346,13 +377,11 @@ def route_commit(Q: jnp.ndarray, valid: jnp.ndarray, inv_rates: jnp.ndarray,
             and cand_valid is not None
         assert prio is None, "prio is a full-variant operand (slot order " \
             "is already random in a sampled candidate list)"
-        B, C = cand_idx.shape
-        Bp = max(8, -(-B // 8) * 8)
+        C = cand_idx.shape[1]
         Cp = max(8, -(-C // 8) * 8)
         pad2 = lambda x, fill: jnp.pad(x.astype(jnp.int32),
                                        ((0, Bp - B), (0, Cp - C)),
                                        constant_values=fill)
-        mask_p = jnp.pad(valid.astype(jnp.int32), (0, Bp - B))[None, :]
         kern = functools.partial(_kernel_pod, m_pad=Mp, c_pad=Cp, b_pad=Bp,
                                  homogeneous=inv_rates.ndim == 1)
         operands = (q_p, pad2(cand_idx, 0), pad2(cand_cls, 3),
@@ -361,12 +390,12 @@ def route_commit(Q: jnp.ndarray, valid: jnp.ndarray, inv_rates: jnp.ndarray,
     q_new, w, sel, scls, val = pl.pallas_call(
         kern,
         out_shape=[
-            jax.ShapeDtypeStruct((Mp, 8), jnp.int32),
-            jax.ShapeDtypeStruct((Mp, 1), jnp.float32),
-            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
-            jax.ShapeDtypeStruct((1, Bp), jnp.int32),
-            jax.ShapeDtypeStruct((1, Bp), jnp.float32),
+            jax.ShapeDtypeStruct((WIDTH, Mp), jnp.int32),
+            jax.ShapeDtypeStruct((1, Mp), jnp.float32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.int32),
+            jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         ],
         interpret=interp,
     )(*operands)
-    return (q_new[:M, :3], w[:M, 0], sel[0, :B], scls[0, :B], val[0, :B])
+    return (q_new[:3, :M].T, w[0, :M], sel[:B, 0], scls[:B, 0], val[:B, 0])

@@ -245,10 +245,7 @@ def run_cell(arch: str, shape_name: str, mesh_kind: str, out_dir: str,
                        "hbm_bytes": cc.hbm_bytes,
                        "params_bytes": cc.params_bytes}
 
-    # jax.set_mesh only exists in newer jax; Mesh is itself a context
-    # manager with the semantics the lowering below needs (named axes
-    # resolvable for NamedSharding / shard_map).
-    with (jax.set_mesh(mesh) if hasattr(jax, "set_mesh") else mesh):
+    with jax.set_mesh(mesh):
         fn, args = build_step(cfg, shape, mesh)
         t0 = time.time()
         lowered = fn.lower(*args)

@@ -13,6 +13,13 @@ from __future__ import annotations
 import os
 
 import jax
+from jax.sharding import AxisType
+
+
+def _auto_mesh(shape, axes):
+    # Auto axes: shardings propagate through the compiler, as the
+    # dry-run's in/out shardings and shard_map stages expect
+    return jax.make_mesh(shape, axes, axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -27,9 +34,9 @@ def make_production_mesh(*, multi_pod: bool = False):
             shape = dims
         elif not multi_pod and len(dims) == 2:
             shape = dims
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)
 
 
 def make_test_mesh(shape=(2, 4), axes=("data", "model")):
     """Small mesh for subprocess tests (8 fake devices)."""
-    return jax.make_mesh(shape, axes)
+    return _auto_mesh(shape, axes)

@@ -23,8 +23,9 @@ Heterogeneous fleets: pass ``rate_matrix`` ([M, 3] per-replica per-class
 service rates, e.g. from repro.core.rate_matrix with scenario speeds).  The
 workload metric and routing scores then divide by each replica's *own*
 rates — and the SAME Pallas kernels serve both forms: their inverse-rate
-operand is [3] or [M, 3] (the per-candidate rate gather rides the kernels'
-existing one-hot matmul), so the router never leaves the MXU path.  A
+operand is [3] or [M, 3] (the per-candidate rate gather rides the same
+one-hot select as the workload gather), so the router never leaves the
+kernel.  A
 zero-rate replica (drained / outage) carries a ``+inf`` inverse rate; the
 kernels mask it to a ``+inf`` score after the multiply, so it is never
 selected while any live candidate exists.  With identical rate-matrix rows

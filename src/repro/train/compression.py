@@ -65,14 +65,6 @@ def _q8(x):
     return q, s.reshape(1)
 
 
-def _axis_size(axis_name: str) -> int:
-    """Static mesh-axis size; jax.lax.axis_size only exists in newer jax."""
-    if hasattr(jax.lax, "axis_size"):
-        return int(jax.lax.axis_size(axis_name))
-    frame = jax.core.axis_frame(axis_name)
-    return int(getattr(frame, "size", frame))
-
-
 def ring_allreduce_q8(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     """Sum ``x`` across ``axis_name`` with int8 wire format.
 
@@ -81,7 +73,7 @@ def ring_allreduce_q8(x: jnp.ndarray, axis_name: str) -> jnp.ndarray:
     exchanged between neighbours (ring reduce-scatter, then ring
     all-gather of the final chunks).
     """
-    n = _axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if n == 1:
         return x
     size = x.size

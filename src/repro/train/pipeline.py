@@ -25,7 +25,6 @@ from typing import Callable
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -96,7 +95,7 @@ def pipeline_forward(layer_fn: Callable, stacked_params, x: jnp.ndarray,
             jnp.where(sid == n_stages - 1, out, jnp.zeros_like(out)), axis)
         return out.reshape(B, *xs.shape[1:])
 
-    prog = shard_map(stage_prog, mesh=mesh,
-                     in_specs=(p_specs, P()), out_specs=P(),
-                     check_rep=False)
+    prog = jax.shard_map(stage_prog, mesh=mesh,
+                         in_specs=(p_specs, P()), out_specs=P(),
+                         check_vma=False)
     return prog(staged, x)

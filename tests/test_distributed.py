@@ -22,13 +22,13 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp, numpy as np
 from jax.sharding import NamedSharding, PartitionSpec as P
-from jax.experimental.shard_map import shard_map
+from repro.launch.mesh import make_test_mesh
 from repro.train import ring_allreduce_q8
 
-mesh = jax.make_mesh((8,), ("pod",))
+mesh = make_test_mesh((8,), ("pod",))
 x = jax.random.normal(jax.random.PRNGKey(0), (8, 1000)) * 2
 
-f = shard_map(lambda s: ring_allreduce_q8(s[0], "pod")[None],
+f = jax.shard_map(lambda s: ring_allreduce_q8(s[0], "pod")[None],
               mesh=mesh, in_specs=P("pod", None), out_specs=P("pod", None))
 got = f(x)   # every shard: the int8-wire ring sum
 want = x.sum(axis=0)
@@ -85,7 +85,8 @@ os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import sys; sys.path.insert(0, "src")
 import jax, jax.numpy as jnp
 from repro.train import pipeline_forward
-mesh = jax.make_mesh((4,), ("pod",))
+from repro.launch.mesh import make_test_mesh
+mesh = make_test_mesh((4,), ("pod",))
 L, D, B = 8, 16, 8
 W = jax.random.normal(jax.random.PRNGKey(0), (L, D, D)) * 0.2
 b = jax.random.normal(jax.random.PRNGKey(1), (L, D)) * 0.1

@@ -165,18 +165,51 @@ print("SHARD_OK")
 """
 
 
-def test_shard_map_matches_single_device():
-    """With 2 forced host devices, the scenario axis shard_maps across
-    them and the result is bit-identical to the single-device vmap —
-    including the uneven (3 scenarios on 2 devices) pad-and-drop."""
+_REUSE_SCRIPT = r"""
+import jax
+from jax import monitoring
+assert jax.device_count() == 2, jax.devices()
+from repro.core import Cluster, Rates, SimConfig, simulate_sweep
+cluster, rates = Cluster(M=16, K=4), Rates(0.05, 0.025, 0.01)
+cfg = SimConfig(T=112, warmup=32, route_mode="batched", s_max=16)
+run = lambda: jax.block_until_ready(simulate_sweep(
+    "balanced_pandas", cluster, rates, [0.5], 2, cfg,
+    scenarios=["uniform", "slow_rack"])[1])
+run()
+compiles = []
+monitoring.register_event_duration_secs_listener(
+    lambda event, secs, **kw: compiles.append(event)
+    if event == "/jax/core/compile/backend_compile_duration" else None)
+run()
+assert not compiles, compiles
+print("REUSE_OK")
+"""
+
+
+def _run_on_two_host_devices(script: str):
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
                         + " --xla_force_host_platform_device_count=2")
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
-    proc = subprocess.run([sys.executable, "-c", _SHARD_SCRIPT],
+    return subprocess.run([sys.executable, "-c", script],
                           capture_output=True, text=True, env=env,
                           timeout=600)
+
+
+def test_sharded_sweep_reuses_its_program():
+    """A second sharded sweep over the same grid compiles nothing: the
+    shard_mapped program is cached like the single-device one."""
+    proc = _run_on_two_host_devices(_REUSE_SCRIPT)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "REUSE_OK" in proc.stdout
+
+
+def test_shard_map_matches_single_device():
+    """With 2 forced host devices, the scenario axis shard_maps across
+    them and the result is bit-identical to the single-device vmap —
+    including the uneven (3 scenarios on 2 devices) pad-and-drop."""
+    proc = _run_on_two_host_devices(_SHARD_SCRIPT)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "SHARD_OK" in proc.stdout
