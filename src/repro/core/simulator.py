@@ -99,6 +99,7 @@ from .cluster import (
 from ..kernels import ref as kernel_ref
 from ..kernels import route_commit as kernel_route_commit
 from ..telemetry import collectors as tlm
+from ..telemetry.spans import op_phases, phase, span
 from ..scenarios.build import (
     ScenarioData,
     placement_epoch_at,
@@ -374,38 +375,50 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
     kernel's class lane is always on).  Probe
     telemetry replays the evolving pre-commit workloads each arrival
     actually routed against (ref.route_commit_wseq), so batched probe
-    ranks are measured against the same O(M) oracle the decision saw."""
+    ranks are measured against the same O(M) oracle the decision saw.
+
+    Phases: the candidate lists or the tie priority are ``candidates``,
+    the routing itself ``route``, the probe replay ``accumulate``."""
     k_tie, k_pod, k_seq = jax.random.split(key, 3)
-    tie_rnd = jax.random.uniform(k_tie, (cluster.M,))
     collect = tcfg is not None and tcfg.probes
     probe = tlm.ZERO_PROBE
 
     if sequential:
+        with phase("candidates"):
+            tie_rnd = jax.random.uniform(k_tie, (cluster.M,))
+
         def route_one(Qc, xs):
             cls_a, loc_a, valid, kr = xs
-            W = _bp_workload(Qc, inv_rates)
-            if pod is None:
-                sel, sc = route_balanced_pandas_full(W, cls_a, inv_rates,
-                                                     tie_rnd, class_tiebreak)
-            else:
+            if pod is not None:
                 kc, kt = jax.random.split(kr)
-                ci, cc, cv = pod_candidates(kc, cluster, loc_a, cls_a, pod)
-                sel, sc = route_pod_candidates(kt, W, ci, cc, cv, inv_rates)
-            Qc = Qc.at[sel, sc].add(valid.astype(jnp.int32))
+                with phase("candidates"):
+                    ci, cc, cv = pod_candidates(kc, cluster, loc_a, cls_a,
+                                                pod)
+            with phase("route"):
+                W = _bp_workload(Qc, inv_rates)
+                if pod is None:
+                    sel, sc = route_balanced_pandas_full(
+                        W, cls_a, inv_rates, tie_rnd, class_tiebreak)
+                else:
+                    sel, sc = route_pod_candidates(kt, W, ci, cc, cv,
+                                                   inv_rates)
+                Qc = Qc.at[sel, sc].add(valid.astype(jnp.int32))
             if collect:
-                full = _full_bp_scores(W, cls_a, inv_rates)
-                return Qc, (sel, sc, full[sel], jnp.min(full),
-                            (full < full[sel]).sum())
+                with phase("accumulate"):
+                    full = _full_bp_scores(W, cls_a, inv_rates)
+                    return Qc, (sel, sc, full[sel], jnp.min(full),
+                                (full < full[sel]).sum())
             return Qc, (sel, sc)
         keys = jax.random.split(k_seq, mask.shape[0])
         Q, ys = jax.lax.scan(route_one, Q, (cls_arr, locals_, mask, keys))
         if collect:
             sel, sel_cls, chosen, best, rank = ys
-            regret = jnp.where(jnp.isfinite(chosen - best), chosen - best,
-                               0.0)
-            v = mask.astype(jnp.float32)
-            probe = ((rank * v).sum(), (jnp.maximum(regret, 0.0) * v).sum(),
-                     v.sum())
+            with phase("accumulate"):
+                regret = jnp.where(jnp.isfinite(chosen - best),
+                                   chosen - best, 0.0)
+                v = mask.astype(jnp.float32)
+                probe = ((rank * v).sum(),
+                         (jnp.maximum(regret, 0.0) * v).sum(), v.sum())
         else:
             sel, sel_cls = ys
     else:
@@ -414,22 +427,30 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
             # same tie semantics as the sequential path: class, then a
             # per-slot random priority (W is lattice-valued, ties are
             # routine; always-lowest-index ties hotspot low-index servers)
-            Q, _W, sel, sel_cls, _val = kernel_route_commit(
-                Q, mask, inv_rates, cls=cls_arr,
-                prio=jax.random.permutation(k_tie, cluster.M))
+            with phase("candidates"):
+                prio = jax.random.permutation(k_tie, cluster.M)
+            with phase("route"):
+                Q, _W, sel, sel_cls, _val = kernel_route_commit(
+                    Q, mask, inv_rates, cls=cls_arr, prio=prio)
         else:
             kc, _ = jax.random.split(k_pod)
-            ci, cc, cv = pod_candidates(kc, cluster, locals_, cls_arr, pod)
-            Q, _W, sel, sel_cls, _val = kernel_route_commit(
-                Q, mask, inv_rates, cand_idx=ci, cand_cls=cc, cand_valid=cv)
+            with phase("candidates"):
+                ci, cc, cv = pod_candidates(kc, cluster, locals_, cls_arr,
+                                            pod)
+            with phase("route"):
+                Q, _W, sel, sel_cls, _val = kernel_route_commit(
+                    Q, mask, inv_rates, cand_idx=ci, cand_cls=cc,
+                    cand_valid=cv)
         if collect:
             # rank each decision against the evolving O(M) oracle: the
             # pre-commit workload row arrival b actually routed against
-            W_seq = kernel_ref.route_commit_wseq(Q0, sel, sel_cls, mask,
-                                                 inv_rates)       # [A, M]
-            full = _full_bp_scores(W_seq, cls_arr, inv_rates)
-            chosen = jnp.take_along_axis(full, sel[:, None], axis=1)[:, 0]
-            probe = tlm.probe_stats_min(full, chosen, mask)
+            with phase("accumulate"):
+                W_seq = kernel_ref.route_commit_wseq(Q0, sel, sel_cls, mask,
+                                                     inv_rates)   # [A, M]
+                full = _full_bp_scores(W_seq, cls_arr, inv_rates)
+                chosen = jnp.take_along_axis(full, sel[:, None],
+                                             axis=1)[:, 0]
+                probe = tlm.probe_stats_min(full, chosen, mask)
     return Q, sel, sel_cls, probe
 
 
@@ -438,44 +459,49 @@ def _bp_step(state: BPState, sums: RawSums, key, *, cluster, rates, cfg,
              homo=False, class_tiebreak=True, t=None, tele=None, tcfg=None):
     k_sched, k_arr, k_route = jax.random.split(key, 3)
 
-    busy, rem, completed = _progress_service(state.busy, state.rem, speed,
-                                             state.cls, homo=homo)
+    with phase("service"):
+        busy, rem, completed = _progress_service(state.busy, state.rem,
+                                                 speed, state.cls, homo=homo)
+        if tcfg is not None:
+            # sojourn = completion slot - arrival slot of the in-service task
+            tele = tlm.record_sojourns(tele, tcfg, t, cfg.warmup, completed)
+    with phase("schedule"):
+        Q, busy, rem, cls_serv, starts, n_started, pick, start = \
+            _bp_schedule(k_sched, state.Q, busy, rem, state.cls, rates,
+                         cfg.service_dist, cfg.sigma,
+                         servable=None if homo else speed > 0, scen=scen)
     if tcfg is not None:
-        # sojourn = completion slot - arrival slot of the in-service task
-        tele = tlm.record_sojourns(tele, tcfg, t, cfg.warmup, completed)
-    Q, busy, rem, cls_serv, starts, n_started, pick, start = _bp_schedule(
-        k_sched, state.Q, busy, rem, state.cls, rates, cfg.service_dist,
-        cfg.sigma, servable=None if homo else speed > 0, scen=scen)
-    if tcfg is not None:
-        m = jnp.arange(cluster.M, dtype=jnp.int32)
-        tele = tlm.ring_pop(tele, tcfg, m * 3 + pick, start, m)
+        with phase("accumulate"):
+            m = jnp.arange(cluster.M, dtype=jnp.int32)
+            tele = tlm.ring_pop(tele, tcfg, m * 3 + pick, start, m)
 
-    mask, locals_, cls_arr, clipped = _arrival_batch(
-        k_arr, cluster, scen, lam_t, a_max, need_cls=True,
-        pe=placement_epoch_at(scen, t))
+    with phase("arrivals"):
+        mask, locals_, cls_arr, clipped = _arrival_batch(
+            k_arr, cluster, scen, lam_t, a_max, need_cls=True,
+            pe=placement_epoch_at(scen, t))
     Q, sel, sel_cls, probe = _bp_route_batch(
         k_route, cluster, Q, cls_arr, locals_, mask, inv_rate_m, pod,
         sequential=(cfg.route_mode == "sequential"),
         class_tiebreak=class_tiebreak, tcfg=tcfg)
-    if tcfg is not None:
-        tele = tlm.ring_push(tele, tcfg, sel * 3 + sel_cls, mask, t)
 
-    routed = (jax.nn.one_hot(sel_cls, 3, dtype=jnp.float32)
-              * mask[:, None].astype(jnp.float32)).sum(axis=0)
-
-    N = Q.sum().astype(jnp.float32) + busy.sum().astype(jnp.float32)
-    sums = _acc(sums, in_half2=in_half2, N=N,
-                arr=mask.sum().astype(jnp.float32), clipped=clipped,
-                comp=completed.sum().astype(jnp.float32), starts=starts,
-                routed=routed, busy_n=busy.sum().astype(jnp.float32),
-                routes=mask.sum().astype(jnp.float32), scheds=n_started,
-                measure=measure)
-    if tcfg is not None:
-        tele = tlm.collect_step(
-            tele, tcfg, t=t, T=cfg.T, N=N, q_mass=Q.sum(axis=0),
-            qlen=Q.sum(axis=1), workload=_bp_workload(Q, inv_rate_m),
-            arrivals=mask.sum(), clipped=clipped,
-            completions=completed.sum(), busy_n=busy.sum(), probe=probe)
+    with phase("accumulate"):
+        if tcfg is not None:
+            tele = tlm.ring_push(tele, tcfg, sel * 3 + sel_cls, mask, t)
+        routed = (jax.nn.one_hot(sel_cls, 3, dtype=jnp.float32)
+                  * mask[:, None].astype(jnp.float32)).sum(axis=0)
+        N = Q.sum().astype(jnp.float32) + busy.sum().astype(jnp.float32)
+        sums = _acc(sums, in_half2=in_half2, N=N,
+                    arr=mask.sum().astype(jnp.float32), clipped=clipped,
+                    comp=completed.sum().astype(jnp.float32), starts=starts,
+                    routed=routed, busy_n=busy.sum().astype(jnp.float32),
+                    routes=mask.sum().astype(jnp.float32), scheds=n_started,
+                    measure=measure)
+        if tcfg is not None:
+            tele = tlm.collect_step(
+                tele, tcfg, t=t, T=cfg.T, N=N, q_mass=Q.sum(axis=0),
+                qlen=Q.sum(axis=1), workload=_bp_workload(Q, inv_rate_m),
+                arrivals=mask.sum(), clipped=clipped,
+                completions=completed.sum(), busy_n=busy.sum(), probe=probe)
     return BPState(Q, busy, rem, cls_serv), sums, tele
 
 
@@ -672,25 +698,31 @@ def _sq_step(state: SQState, sums: RawSums, key, *, cluster, rates, cfg,
              in_half2, homo=False, t=None, tele=None, tcfg=None):
     k_sched, k_arr, k_route = jax.random.split(key, 3)
 
-    busy, rem, completed = _progress_service(state.busy, state.rem, speed,
-                                             state.cls, homo=homo)
+    with phase("service"):
+        busy, rem, completed = _progress_service(state.busy, state.rem,
+                                                 speed, state.cls, homo=homo)
+        if tcfg is not None:
+            tele = tlm.record_sojourns(tele, tcfg, t, cfg.warmup, completed)
+    with phase("schedule"):
+        Q, busy, rem, cls_serv, starts, n_sched, rows, tgt, granted, probe = \
+            _sq_schedule(k_sched, cluster, state.Q, busy, rem, state.cls,
+                         rates, cfg, variant, pod, speed, homo=homo,
+                         tcfg=tcfg, scen=scen)
     if tcfg is not None:
-        tele = tlm.record_sojourns(tele, tcfg, t, cfg.warmup, completed)
-    Q, busy, rem, cls_serv, starts, n_sched, rows, tgt, granted, probe = \
-        _sq_schedule(k_sched, cluster, state.Q, busy, rem, state.cls, rates,
-                     cfg, variant, pod, speed, homo=homo, tcfg=tcfg,
-                     scen=scen)
-    if tcfg is not None:
-        tele = tlm.ring_pop(tele, tcfg, tgt, granted, rows)
+        with phase("accumulate"):
+            tele = tlm.ring_pop(tele, tcfg, tgt, granted, rows)
 
-    mask, locals_, _cls, clipped = _arrival_batch(
-        k_arr, cluster, scen, lam_t, a_max, need_cls=False,
-        pe=placement_epoch_at(scen, t))
+    with phase("arrivals"):
+        mask, locals_, _cls, clipped = _arrival_batch(
+            k_arr, cluster, scen, lam_t, a_max, need_cls=False,
+            pe=placement_epoch_at(scen, t))
+    # an arrival's candidates are its replica servers (drawn in arrivals)
     if cfg.route_mode == "sequential":
         def route_one(Qc, xs):
             loc, valid, kr = xs
-            sel = route_jsq_local(kr, Qc, loc)
-            return Qc.at[sel].add(valid.astype(jnp.int32)), sel
+            with phase("route"):
+                sel = route_jsq_local(kr, Qc, loc)
+                return Qc.at[sel].add(valid.astype(jnp.int32)), sel
         keys = jax.random.split(k_route, a_max)
         Q, sel = jax.lax.scan(route_one, Q, (locals_, mask, keys))
     else:
@@ -699,38 +731,40 @@ def _sq_step(state: SQState, sums: RawSums, key, *, cluster, rates, cfg,
         # arrival sees the previous one's commit (no snapshot herding).
         # Ties break by replica slot order (vs the sequential path's
         # random pick) — a documented batched-mode contract difference.
-        Q3 = jnp.zeros((cluster.M, 3), jnp.int32).at[:, 0].set(Q)
-        Q3, _W, sel, _scls, _val = kernel_route_commit(
-            Q3, mask, jnp.ones(3, jnp.float32), cand_idx=locals_,
-            cand_cls=jnp.zeros_like(locals_),
-            cand_valid=jnp.ones_like(locals_))
-        Q = Q3[:, 0]
-    if tcfg is not None:
-        tele = tlm.ring_push(tele, tcfg, sel, mask, t)
+        with phase("route"):
+            Q3 = jnp.zeros((cluster.M, 3), jnp.int32).at[:, 0].set(Q)
+            Q3, _W, sel, _scls, _val = kernel_route_commit(
+                Q3, mask, jnp.ones(3, jnp.float32), cand_idx=locals_,
+                cand_cls=jnp.zeros_like(locals_),
+                cand_valid=jnp.ones_like(locals_))
+            Q = Q3[:, 0]
 
-    N = Q.sum().astype(jnp.float32) + busy.sum().astype(jnp.float32)
-    sums = _acc(sums, in_half2=in_half2, N=N,
-                arr=mask.sum().astype(jnp.float32), clipped=clipped,
-                comp=completed.sum().astype(jnp.float32), starts=starts,
-                routed=jnp.zeros(3, jnp.float32),
-                busy_n=busy.sum().astype(jnp.float32),
-                routes=mask.sum().astype(jnp.float32), scheds=n_sched,
-                measure=measure)
-    if tcfg is not None:
-        # workload proxy: queued work at the local rate (JSQ queues are
-        # local to their server); drained servers contribute 0
-        inv_l = inv_rate_m[:, LOCAL] if inv_rate_m.ndim == 2 \
-            else jnp.full((cluster.M,), inv_rate_m[LOCAL])
-        inv_l = jnp.where(jnp.isfinite(inv_l), inv_l, 0.0)
-        tele = tlm.collect_step(
-            tele, tcfg, t=t, T=cfg.T, N=N,
-            q_mass=jnp.stack([Q.sum().astype(jnp.float32),
-                              jnp.float32(0.0), jnp.float32(0.0)]),
-            qlen=Q, workload=Q.astype(jnp.float32) * inv_l,
-            arrivals=mask.sum(), clipped=clipped,
-            completions=completed.sum(), busy_n=busy.sum(), probe=probe)
-    else:
-        del inv_rate_m  # JSQ routing is workload-metric-free
+    with phase("accumulate"):
+        if tcfg is not None:
+            tele = tlm.ring_push(tele, tcfg, sel, mask, t)
+        N = Q.sum().astype(jnp.float32) + busy.sum().astype(jnp.float32)
+        sums = _acc(sums, in_half2=in_half2, N=N,
+                    arr=mask.sum().astype(jnp.float32), clipped=clipped,
+                    comp=completed.sum().astype(jnp.float32), starts=starts,
+                    routed=jnp.zeros(3, jnp.float32),
+                    busy_n=busy.sum().astype(jnp.float32),
+                    routes=mask.sum().astype(jnp.float32), scheds=n_sched,
+                    measure=measure)
+        if tcfg is not None:
+            # workload proxy: queued work at the local rate (JSQ queues
+            # are local to their server); drained servers contribute 0
+            inv_l = inv_rate_m[:, LOCAL] if inv_rate_m.ndim == 2 \
+                else jnp.full((cluster.M,), inv_rate_m[LOCAL])
+            inv_l = jnp.where(jnp.isfinite(inv_l), inv_l, 0.0)
+            tele = tlm.collect_step(
+                tele, tcfg, t=t, T=cfg.T, N=N,
+                q_mass=jnp.stack([Q.sum().astype(jnp.float32),
+                                  jnp.float32(0.0), jnp.float32(0.0)]),
+                qlen=Q, workload=Q.astype(jnp.float32) * inv_l,
+                arrivals=mask.sum(), clipped=clipped,
+                completions=completed.sum(), busy_n=busy.sum(), probe=probe)
+        else:
+            del inv_rate_m  # JSQ routing is workload-metric-free
     return SQState(Q, busy, rem, cls_serv), sums, tele
 
 
@@ -1126,9 +1160,9 @@ def _sweep_cells(keys, lam, scen, *, algo, cluster, rates, cfg, pod, a_max,
     keys: [K] PRNG keys (one per Monte-Carlo seed, shared across cells the
     way ``simulate_grid`` shares them across loads); lam: [S, L]; scen:
     ScenarioData with leading [S].  Returns (sums, tele) with leading
-    [S, K, L] on every leaf.  The jit boundary stays on ``_run``, so the
-    whole grid lowers to ONE batched executable per policy signature and
-    ``trace_count`` advances by exactly 1.
+    [S, K, L] on every leaf.  The whole grid lowers to ONE batched
+    executable per policy signature and ``trace_count`` advances by
+    exactly 1.
     """
     def one(key, l, sc):
         return _run(key, l, sc, algo=algo, cluster=cluster, rates=rates,
@@ -1143,10 +1177,14 @@ def _sweep_cells(keys, lam, scen, *, algo, cluster, rates, cfg, pod, a_max,
     return jax.vmap(per_scen)(lam, scen)
 
 
-@functools.partial(
-    jax.jit,
-    static_argnames=("mesh", "algo", "cluster", "rates", "cfg", "pod",
-                     "a_max", "tcfg"))
+_SWEEP_STATIC = ("algo", "cluster", "rates", "cfg", "pod", "a_max", "tcfg")
+
+# one device: the grid as one jitted program, so the sweep owns one
+# executable per signature and can read back its compiled text
+_sweep_jit = jax.jit(_sweep_cells, static_argnames=_SWEEP_STATIC)
+
+
+@functools.partial(jax.jit, static_argnames=("mesh",) + _SWEEP_STATIC)
 def _sweep_sharded(keys, lam, scen, *, mesh, algo, cluster, rates, cfg, pod,
                    a_max, tcfg):
     """``_sweep_cells`` with the scenario axis shard_mapped over ``mesh``.
@@ -1161,6 +1199,48 @@ def _sweep_sharded(keys, lam, scen, *, mesh, algo, cluster, rates, cfg, pod,
         check_vma=False)(keys, lam, scen)
 
 
+# what the last simulate_sweep dispatched: the jitted function, its
+# arguments' shapes and its static arguments (and, once asked for, the
+# phase map of that executable)
+_LAST_SWEEP: dict = {}
+
+
+def _abstract(x):
+    """Shape and dtype of a dispatched argument, and its placement where
+    it was committed to one (jit lowers an uncommitted argument without
+    a sharding, and so must the recorded signature)."""
+    committed = getattr(x, "committed", False)
+    return jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                sharding=x.sharding if committed else None)
+
+
+def sweep_op_phases() -> dict:
+    """``telemetry.spans.op_phases`` of the executable the last
+    ``simulate_sweep`` call ran: ``{HLO instruction name: phase}``, the
+    names a device trace of that call shows.
+
+    Lowers and compiles the recorded signature on demand (a load where the
+    persistent compile cache holds it) and keeps the map until the next
+    call changes the signature; never on the dispatch path.  Empty before
+    the first sweep."""
+    if not _LAST_SWEEP:
+        return {}
+    if "phases" not in _LAST_SWEEP:
+        fn, args, kw = (_LAST_SWEEP[k] for k in ("fn", "args", "kw"))
+        text = fn.lower(*args, **kw).compile().as_text()
+        _LAST_SWEEP["phases"] = op_phases(text)
+    return _LAST_SWEEP["phases"]
+
+
+def _record_sweep(fn, args, kw) -> None:
+    args = jax.tree_util.tree_map(_abstract, args)
+    if _LAST_SWEEP.get("fn") is fn and _LAST_SWEEP.get("args") == args \
+            and _LAST_SWEEP.get("kw") == kw:
+        return                       # same executable: keep its phase map
+    _LAST_SWEEP.clear()
+    _LAST_SWEEP.update(fn=fn, args=args, kw=kw)
+
+
 def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
                    n_seeds: int, cfg: SimConfig = SimConfig(),
                    pod: Optional[PodSpec] = None, seed0: int = 0,
@@ -1172,16 +1252,23 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
     (``scenarios.build.stack_scenarios``), vmaps the jit'd simulator over
     scenario x seed x load, and — when more than one device is visible —
     shard_maps the scenario axis across devices (single-device hosts, e.g.
-    CPU CI, fall back to the plain vmap; pass ``devices`` to restrict the
-    mesh).  The policy (``algo``, ``pod``) is a static branch: each policy
-    is its own compiled program, and ``trace_count`` advances by exactly 1
-    per policy for the entire grid (tests/test_sweep.py guards this).
+    CPU CI, run the plain vmap as one jitted program; pass ``devices`` to
+    restrict the mesh).  The policy (``algo``, ``pod``) is a static
+    branch: each policy is its own compiled program, and ``trace_count``
+    advances by exactly 1 per policy for the entire grid
+    (tests/test_sweep.py guards this).
 
     Per-cell PRNG: seed k of every (scenario, load) cell uses key
     ``jax.random.split(PRNGKey(seed0), n_seeds)[k]`` — exactly the keys
     ``simulate_grid`` uses, so each cell of the one-program sweep is
     BIT-IDENTICAL to the corresponding looped ``simulate_grid`` cell
     (also guarded by tests/test_sweep.py).
+
+    Host spans (``telemetry.spans``): ``sweep.call`` around the call, with
+    ``sweep.grid`` (stacking the grid), ``sweep.launch`` (keys and
+    dispatch, until the program's futures return) and ``sweep.summarize``
+    inside it.  ``sweep_op_phases()`` maps the dispatched program's
+    instructions to the slot step's phases.
 
     Returns ``(names, SimResult, telemetry)`` where every SimResult leaf
     carries leading dims ``[n_scenarios, n_seeds, n_loads]`` and
@@ -1190,36 +1277,44 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
     ``repro.telemetry.export.cell_view`` — never aggregate across cells).
     """
     import numpy as _np
-    names, scen, lam, a_max = sweep_grid(cluster, rates, cfg, loads,
-                                         scenarios=scenarios, pad=pad,
-                                         a_max=a_max)
-    pod = _pod_for(algo, pod)
-    keys = jax.random.split(jax.random.PRNGKey(seed0), n_seeds)
-    kw = dict(algo=algo, cluster=cluster, rates=rates, cfg=cfg, pod=pod,
-              a_max=a_max, tcfg=telemetry)
+    with span("sweep.call"):
+        with span("sweep.grid"):
+            names, scen, lam, a_max = sweep_grid(
+                cluster, rates, cfg, loads, scenarios=scenarios, pad=pad,
+                a_max=a_max)
+        with span("sweep.launch"):
+            pod = _pod_for(algo, pod)
+            keys = jax.random.split(jax.random.PRNGKey(seed0), n_seeds)
+            kw = dict(algo=algo, cluster=cluster, rates=rates, cfg=cfg,
+                      pod=pod, a_max=a_max, tcfg=telemetry)
 
-    devs = list(devices) if devices is not None else jax.devices()
-    S = lam.shape[0]
-    D = min(len(devs), S)
-    if D > 1:
-        from jax.sharding import Mesh
+            devs = list(devices) if devices is not None else jax.devices()
+            S = lam.shape[0]
+            D = min(len(devs), S)
+            if D > 1:
+                from jax.sharding import Mesh
 
-        pad_s = (-S) % D
-        if pad_s:
-            # repeat trailing scenarios so the scenario axis divides the
-            # mesh evenly; the duplicate rows are dropped below
-            rep = lambda x: jnp.concatenate([x, x[-pad_s:]], axis=0)
-            scen = jax.tree_util.tree_map(rep, scen)
-            lam = rep(lam)
-        mesh = Mesh(_np.asarray(devs[:D]), ("scen",))
-        sums, tele = _sweep_sharded(keys, lam, scen, mesh=mesh, **kw)
-        if pad_s:
-            drop = lambda x: x[:S]
-            sums = jax.tree_util.tree_map(drop, sums)
-            tele = jax.tree_util.tree_map(drop, tele)
-    else:
-        sums, tele = _sweep_cells(keys, lam, scen, **kw)
-    return names, summarize(sums, algo, cluster, rates, pod), tele
+                pad_s = (-S) % D
+                if pad_s:
+                    # repeat trailing scenarios so the scenario axis
+                    # divides the mesh evenly; the duplicate rows are
+                    # dropped below
+                    rep = lambda x: jnp.concatenate([x, x[-pad_s:]], axis=0)
+                    scen = jax.tree_util.tree_map(rep, scen)
+                    lam = rep(lam)
+                kw["mesh"] = Mesh(_np.asarray(devs[:D]), ("scen",))
+                sums, tele = _sweep_sharded(keys, lam, scen, **kw)
+                _record_sweep(_sweep_sharded, (keys, lam, scen), kw)
+                if pad_s:
+                    drop = lambda x: x[:S]
+                    sums = jax.tree_util.tree_map(drop, sums)
+                    tele = jax.tree_util.tree_map(drop, tele)
+            else:
+                sums, tele = _sweep_jit(keys, lam, scen, **kw)
+                _record_sweep(_sweep_jit, (keys, lam, scen), kw)
+        with span("sweep.summarize"):
+            res = summarize(sums, algo, cluster, rates, pod)
+    return names, res, tele
 
 
 def summarize(s: RawSums, algo: str, cluster: Cluster, rates: Rates,

@@ -397,5 +397,8 @@ def route_commit(Q: jnp.ndarray, valid: jnp.ndarray, inv_rates: jnp.ndarray,
             jax.ShapeDtypeStruct((Bp, 1), jnp.float32),
         ],
         interpret=interp,
+        # the device instruction is then ``route_commit.N`` whatever
+        # jit, vmap or scope wraps the call (profiles find it by name)
+        name="route_commit",
     )(*operands)
     return (q_new[:3, :M].T, w[0, :M], sel[:B, 0], scls[:B, 0], val[:B, 0])

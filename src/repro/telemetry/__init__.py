@@ -56,6 +56,18 @@ and validated by ``scripts/validate_telemetry.py`` in CI.
 
 Entry points: ``core.simulate_with_telemetry`` /
 ``core.simulate_grid_with_telemetry`` return ``(SimResult, Telemetry)``.
+
+Time, not statistics: ``spans``
+-------------------------------
+The collectors above record what the simulated fleet did; ``spans``
+records where the program's time went.  ``phase(name)`` scopes the slot
+step's six phases (``PHASES``) in the compiled program's metadata, and
+``op_phases`` maps a compiled module's instructions back to them
+(``core.simulator.sweep_op_phases()`` for the last sweep).  ``span(name)``
+marks host work in the profiler trace and in an in-memory buffer read by
+``spans()`` and ``span_totals()``.  (The function ``spans`` shadows the
+submodule of the same name here: import from ``repro.telemetry.spans``
+for the module's other names.)
 """
 from .collectors import (
     WINDOW_MAXES,
@@ -90,6 +102,17 @@ from .export import (
     windowed_drift,
     write_jsonl,
 )
+from .spans import (
+    CONTAINER,
+    PHASES,
+    Span,
+    op_phases,
+    phase,
+    span,
+    spans,
+)
+from .spans import reset as reset_spans
+from .spans import totals as span_totals
 from .hist import (
     BINS_PER_OCTAVE,
     N_BINS,

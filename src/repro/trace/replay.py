@@ -58,6 +58,7 @@ from ..core.simulator import (
 from ..core.policies import PodSpec, pod_candidates
 from ..kernels import route_commit as kernel_route_commit
 from ..telemetry import collectors as tlm
+from ..telemetry.spans import phase, span
 from ..scenarios.build import realize
 from .compile import arrival_rows, scenario_from_trace
 from .format import ArrivalLog, ensure_valid
@@ -114,11 +115,14 @@ def _replay_chunk(state: BPState, sums: RawSums, tele, locals_c, mask_c,
 
     # vectorized chunk prep: everything per-arrival that does not depend
     # on queue state happens once, outside the slot scan
-    cls_c = locality_class(cluster, locals_c)              # [S, A, M]
+    with phase("arrivals"):
+        cls_c = locality_class(cluster, locals_c)          # [S, A, M]
     if not full_bp:
         k_cand, key = jax.random.split(key)
-        ci, cc, cv = pod_candidates(k_cand, cluster, locals_c, cls_c, pod)
-        cv = cv & mask_c[..., None]
+        with phase("candidates"):
+            ci, cc, cv = pod_candidates(k_cand, cluster, locals_c, cls_c,
+                                        pod)
+            cv = cv & mask_c[..., None]
 
     def slot_step(carry, s):
         state, sums, tele = carry
@@ -126,39 +130,45 @@ def _replay_chunk(state: BPState, sums: RawSums, tele, locals_c, mask_c,
         k = jax.random.fold_in(key, s)
         k_sched, k_tie = jax.random.split(k)
         measure = (t >= cfg.warmup) & (t < cfg.T)
-        busy, rem, completed = _progress_service(
-            state.busy, state.rem, None, state.cls, homo=True)
-        Q, busy, rem, cls_serv, starts, n_started, _pick, _start = \
-            _bp_schedule(k_sched, state.Q, busy, rem, state.cls, rates,
-                         cfg.service_dist, cfg.sigma, servable=None,
-                         scen=sizes)
+        with phase("service"):
+            busy, rem, completed = _progress_service(
+                state.busy, state.rem, None, state.cls, homo=True)
+        with phase("schedule"):
+            Q, busy, rem, cls_serv, starts, n_started, _pick, _start = \
+                _bp_schedule(k_sched, state.Q, busy, rem, state.cls, rates,
+                             cfg.service_dist, cfg.sigma, servable=None,
+                             scen=sizes)
         mask_t = mask_c[s]
         if full_bp:
-            Q, _W, sel, sel_cls, _val = kernel_route_commit(
-                Q, mask_t, inv_rates, cls=cls_c[s],
-                prio=jax.random.permutation(k_tie, cluster.M))
+            with phase("candidates"):
+                prio = jax.random.permutation(k_tie, cluster.M)
+            with phase("route"):
+                Q, _W, sel, sel_cls, _val = kernel_route_commit(
+                    Q, mask_t, inv_rates, cls=cls_c[s], prio=prio)
         else:
-            Q, _W, sel, sel_cls, _val = kernel_route_commit(
-                Q, mask_t, inv_rates, cand_idx=ci[s], cand_cls=cc[s],
-                cand_valid=cv[s])
-        routed = (jax.nn.one_hot(sel_cls, 3, dtype=jnp.float32)
-                  * mask_t[:, None].astype(jnp.float32)).sum(axis=0)
-        N = Q.sum().astype(jnp.float32) + busy.sum().astype(jnp.float32)
-        sums = _acc(sums, in_half2=(t >= half2_from), N=N,
-                    arr=mask_t.sum().astype(jnp.float32),
-                    clipped=jnp.float32(0.0),   # replay never clips
-                    comp=completed.sum().astype(jnp.float32),
-                    starts=starts, routed=routed,
-                    busy_n=busy.sum().astype(jnp.float32),
-                    routes=mask_t.sum().astype(jnp.float32),
-                    scheds=n_started, measure=measure)
-        if tcfg is not None:
-            tele = tlm.collect_step(
-                tele, tcfg, t=t, T=t_pad, N=N, q_mass=Q.sum(axis=0),
-                qlen=Q.sum(axis=1), workload=_bp_workload(Q, inv_rates),
-                arrivals=mask_t.sum(), clipped=jnp.float32(0.0),
-                completions=completed.sum(), busy_n=busy.sum(),
-                probe=tlm.ZERO_PROBE)
+            with phase("route"):
+                Q, _W, sel, sel_cls, _val = kernel_route_commit(
+                    Q, mask_t, inv_rates, cand_idx=ci[s], cand_cls=cc[s],
+                    cand_valid=cv[s])
+        with phase("accumulate"):
+            routed = (jax.nn.one_hot(sel_cls, 3, dtype=jnp.float32)
+                      * mask_t[:, None].astype(jnp.float32)).sum(axis=0)
+            N = Q.sum().astype(jnp.float32) + busy.sum().astype(jnp.float32)
+            sums = _acc(sums, in_half2=(t >= half2_from), N=N,
+                        arr=mask_t.sum().astype(jnp.float32),
+                        clipped=jnp.float32(0.0),   # replay never clips
+                        comp=completed.sum().astype(jnp.float32),
+                        starts=starts, routed=routed,
+                        busy_n=busy.sum().astype(jnp.float32),
+                        routes=mask_t.sum().astype(jnp.float32),
+                        scheds=n_started, measure=measure)
+            if tcfg is not None:
+                tele = tlm.collect_step(
+                    tele, tcfg, t=t, T=t_pad, N=N, q_mass=Q.sum(axis=0),
+                    qlen=Q.sum(axis=1), workload=_bp_workload(Q, inv_rates),
+                    arrivals=mask_t.sum(), clipped=jnp.float32(0.0),
+                    completions=completed.sum(), busy_n=busy.sum(),
+                    probe=tlm.ZERO_PROBE)
         return (BPState(Q, busy, rem, cls_serv), sums, tele), None
 
     (state, sums, tele), _ = jax.lax.scan(
@@ -243,8 +253,10 @@ class ReplayEngine:
                 if self.tcfg is not None else None)
         kw = self._step_kwargs()
         traces0 = replay_trace_count()
-        put = lambda c: (jax.device_put(self._chunks[c][0]),
-                         jax.device_put(self._chunks[c][1]))
+        def put(c):
+            with span("replay.put"):
+                return (jax.device_put(self._chunks[c][0]),
+                        jax.device_put(self._chunks[c][1]))
         t_start = time.perf_counter()
         nxt = put(0)
         with warnings.catch_warnings():
@@ -256,11 +268,13 @@ class ReplayEngine:
                 cur = nxt
                 if c + 1 < self.n_chunks:
                     nxt = put(c + 1)  # H2D for c+1 overlaps chunk c compute
-                state, sums, tele = _replay_chunk(
-                    state, sums, tele, cur[0], cur[1],
-                    jnp.int32(c * self.chunk_slots), self._sizes,
-                    jax.random.fold_in(key, c), **kw)
-        jax.block_until_ready(sums)
+                with span("replay.chunk"):
+                    state, sums, tele = _replay_chunk(
+                        state, sums, tele, cur[0], cur[1],
+                        jnp.int32(c * self.chunk_slots), self._sizes,
+                        jax.random.fold_in(key, c), **kw)
+        with span("replay.sync"):
+            jax.block_until_ready(sums)
         wall = time.perf_counter() - t_start
         n = self.log.n_tasks
         return ReplayResult(

@@ -7,16 +7,38 @@ TPU compiler for a described v5e chip (no chip attached): at the paper's
 M=500 fleet and at M=2048, plus the vmapped form the one-program sweep
 lowers to.  Nothing runs, so results are checked elsewhere (test_kernels
 here, ``chip_smoke.py`` on the chip).
+
+The last tests compile the one-device sweep at M=500 (T=8 slots, seconds
+each) with the kernel in Mosaic, as on the chip, and read its phase map
+(``telemetry.spans.op_phases``).  On the TPU, XLA emits the cumulative
+sums of the pod candidate draws as ``reduce-window`` instructions with no
+``op_name``, and the random permutation of full Balanced-Pandas as
+``sort``s: each must still land in ``candidates``, and the kernel must
+keep its name.  All described-chip compiles stay in this one file: only
+one process at a time may load the TPU's library.
 """
+import collections
+import re
+import sys
+
 import pytest
 
 import jax
 import jax.numpy as jnp
 from jax.sharding import SingleDeviceSharding
 
-from repro.core import Cluster, Rates, SimConfig
+from repro.core import Cluster, Rates, SimConfig, sweep_grid
+from repro.core import simulator as sim
 from repro.core.simulator import BP_POD_DEFAULT
 from repro.kernels import route_commit
+from repro.telemetry.spans import (
+    CONTAINER_OPS,
+    KERNEL,
+    PHASES,
+    PLUMBING_OPS,
+    op_phases,
+    top_level,
+)
 
 PAPER_RATES = Rates(0.01, 0.005, 0.002)
 
@@ -67,8 +89,17 @@ def _call(variant: str):
         q, v, i, cand_idx=a, cand_cls=c, cand_valid=ok, interpret=False)
 
 
+def _mosaic_calls(text: str) -> list:
+    """Names of the Mosaic custom calls in a compiled module's text."""
+    return re.findall(r'%([\w.\-]+) = [^\n]*'
+                      r'custom_call_target="tpu_custom_call"', text)
+
+
 def _assert_mosaic(compiled):
-    assert "tpu_custom_call" in compiled.as_text()
+    """The kernel is one Mosaic custom call, named ``route_commit.N``
+    whatever wraps it (the name a device trace shows)."""
+    calls = _mosaic_calls(compiled.as_text())
+    assert [c.split(".")[0] for c in calls] == [KERNEL], calls
 
 
 @pytest.mark.parametrize("M", [500, 2048])
@@ -87,3 +118,100 @@ def test_vmapped_route_commit_compiles_for_v5e(one_chip, variant):
     compiled = jax.jit(jax.vmap(jax.vmap(_call(variant)))).lower(
         *_operands(one_chip, variant, 500, True, lead=(2, 4))).compile()
     _assert_mosaic(compiled)
+
+
+# ---------------------------------------------------------------------------
+# the one-device sweep's phase map
+# ---------------------------------------------------------------------------
+
+PAPER = Cluster(M=500, K=10)
+SWEEP_CFG = SimConfig(T=8, warmup=2, route_mode="batched")
+
+
+@pytest.fixture(scope="module")
+def mosaic(one_chip):
+    """``one_chip`` with the simulator's kernel in Mosaic, as on the chip
+    (the CPU backend would pick the Pallas interpreter).  Traces cached
+    under the CPU's choice are dropped first, and the Mosaic ones after,
+    so that no other test reuses them."""
+    kernel_module = sys.modules["repro.kernels.route_commit"]
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(kernel_module, "resolve_interpret", lambda _: False)
+        jax.clear_caches()
+        try:
+            yield one_chip
+        finally:
+            jax.clear_caches()
+
+
+def _compiled_sweep(sharding, algo: str, scenarios, loads) -> str:
+    _, scen, lam, a_max = sweep_grid(PAPER, PAPER_RATES, SWEEP_CFG, loads,
+                                     scenarios=scenarios)
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+    args = jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        (keys, lam, scen))
+    return sim._sweep_jit.lower(
+        *args, algo=algo, cluster=PAPER, rates=PAPER_RATES, cfg=SWEEP_CFG,
+        pod=sim._pod_for(algo, None), a_max=a_max,
+        tcfg=None).compile().as_text()
+
+
+@pytest.fixture(scope="module", params=["balanced_pandas_pod",
+                                        "balanced_pandas"])
+def compiled(request, mosaic):
+    """(algo, compiled text, phase map) of an M=500 sweep for the v5e."""
+    algo = request.param
+    if algo == "balanced_pandas_pod":      # the m500-bppod-sweep grid
+        text = _compiled_sweep(mosaic, algo, ["uniform", "slow_rack"],
+                               [0.5, 0.9])
+    else:
+        text = _compiled_sweep(mosaic, algo, ["uniform"], [0.9])
+    return algo, text, op_phases(text)
+
+
+def test_kernel_is_one_custom_call_named_route_commit(compiled):
+    _, text, phases = compiled
+    calls = _mosaic_calls(text)
+    assert [c.split(".")[0] for c in calls] == [KERNEL], calls
+    assert phases[calls[0]] == "route"
+
+
+def test_cumsums_and_permutation_sorts_are_candidates(compiled):
+    algo, text, phases = compiled
+    instrs = top_level(text)
+    windows = [i for i in instrs if i.opcode == "reduce-window"]
+    shuffles = [i for i in instrs if i.opcode == "sort"
+                and "_shuffle" in i.op_name]
+    if algo == "balanced_pandas_pod":
+        assert windows               # masked_draws' cumulative sums
+    else:
+        assert shuffles              # the tie priority's permutation
+    for i in windows + shuffles:
+        assert phases.get(i.name) == "candidates", i
+
+
+def test_class_tensor_is_arrivals(compiled):
+    _, text, phases = compiled
+    classes = [i for i in top_level(text)
+               if "slot.arrivals" in i.op_name
+               and i.name.startswith("iota_reduce_fusion")]
+    assert classes
+    for i in classes:
+        assert phases[i.name] == "arrivals"
+
+
+def test_no_leaf_of_the_scan_body_is_left_without_a_phase(compiled):
+    _, text, phases = compiled
+    instrs = top_level(text)
+    entry = instrs[0].computation
+    sizes = collections.Counter(i.computation for i in instrs)
+    body = max((c for i in instrs if i.computation == entry
+                and i.opcode == "while" for c in i.callees),
+               key=lambda c: sizes[c])
+    leaves = [i for i in instrs if i.computation == body
+              and i.opcode not in CONTAINER_OPS
+              and i.opcode not in PLUMBING_OPS]
+    assert leaves
+    assert [i.name for i in leaves if phases.get(i.name) not in PHASES] \
+        == []
