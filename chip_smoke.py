@@ -82,8 +82,7 @@ def kernel_operands(cluster, rates, seed: int, B: int):
     k_loc, k_cand = jax.random.split(jax.random.PRNGKey(seed))
     locals_ = sample_locals(k_loc, cluster, B)
     cls = locality_class(cluster, locals_)
-    ci, cc, cv = pod_candidates(k_cand, cluster, locals_, cls,
-                                BP_POD_DEFAULT)
+    ci, cc, cv = pod_candidates(k_cand, cluster, locals_, BP_POD_DEFAULT)
     Q = jnp.asarray(rng.integers(0, 40, (cluster.M, 3)), jnp.int32)
     valid = jnp.asarray(rng.random(B) < 0.85)
     prio = jnp.asarray(rng.permutation(cluster.M), jnp.int32)

@@ -17,9 +17,14 @@ Sampling model: Pod candidates are drawn uniformly **with replacement** from
 the rack-local / remote sets (the standard Mitzenmacher power-of-d model;
 the collision probability for d=8 out of hundreds is <3% and only ever
 *shrinks* the effective d, i.e. it is conservative for the paper's claims).
-Draws use cumulative-count inversion (cumsum + searchsorted), which is O(M)
-per task instead of the O(M log M) Gumbel-top-k a without-replacement draw
-would need — this is the simulator's innermost loop.
+Servers are contiguous by rack, so both sets are unions of index
+intervals fixed by the task's replica triple: rack-local is the triple's
+1-3 distinct racks minus the replicas, remote is every other rack.  A draw
+picks u uniformly below the set's size and maps it to the (u+1)-th member
+by O(n_replicas) integer skips over the sorted triple — no per-server
+mask, O(1) per task as the paper's complexity claim has it.
+``masked_draws`` (cumulative-count inversion, O(M) per task) is the
+reference these draws are tested against: same keys, same indices.
 """
 from __future__ import annotations
 
@@ -29,7 +34,7 @@ from typing import Optional
 import jax
 import jax.numpy as jnp
 
-from .cluster import LOCAL, RACK, REMOTE, Cluster, locality_class
+from .cluster import LOCAL, RACK, REMOTE, Cluster
 
 _INF = jnp.inf
 
@@ -60,20 +65,34 @@ def masked_draws(key: jax.Array, set_mask: jnp.ndarray, k: int) -> tuple[jnp.nda
     """k uniform-with-replacement draws from each row of ``set_mask``.
 
     set_mask: bool [..., M].  Returns (idx int32 [..., k], valid bool [..., k]);
-    rows with an empty set yield valid=False.  Inversion sampling: the
-    (u+1)-th set member is the first index where cumsum(mask) > u.
+    rows with an empty set yield valid=False and index M-1.  Inversion
+    sampling: the (u+1)-th set member is the first index where
+    cumsum(mask) > u.  O(M) per row: the reference ``pod_candidates``'
+    interval draws are tested against (identical for the same key).
     """
     csum = jnp.cumsum(set_mask.astype(jnp.int32), axis=-1)
     total = csum[..., -1]
     u = jax.random.randint(key, set_mask.shape[:-1] + (k,), 0,
                            jnp.maximum(total, 1)[..., None])
     # searchsorted(csum, u, 'right') == #(csum <= u): one fused counting op
-    # over [..., k, M] instead of a vmapped binary search (hot path: every
-    # pod-candidate draw, every slot)
+    # over [..., k, M] instead of a vmapped binary search
     idx = jnp.sum((csum[..., None, :] <= u[..., :, None]).astype(jnp.int32),
                   axis=-1)
     valid = jnp.broadcast_to((total > 0)[..., None], idx.shape)
     return jnp.minimum(idx, set_mask.shape[-1] - 1), valid
+
+
+def _interval_draws(key: jax.Array, size: jnp.ndarray, k: int, skip,
+                    M: int) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """k draws u ~ U[0, size) per row, mapped to server ids by ``skip``.
+
+    size: int32 [...] set sizes.  The draw and the empty-set contract
+    (valid=False, index M-1) are ``masked_draws``' own, so the two agree
+    draw for draw wherever ``skip`` maps u to the (u+1)-th set member."""
+    u = jax.random.randint(key, size.shape + (k,), 0,
+                           jnp.maximum(size, 1)[..., None])
+    ok = jnp.broadcast_to((size > 0)[..., None], u.shape)
+    return jnp.where(ok, skip(u), M - 1), ok
 
 
 def weighted_score(W: jnp.ndarray, inv: jnp.ndarray) -> jnp.ndarray:
@@ -119,21 +138,56 @@ def pod_candidates(
     key: jax.Array,
     cluster: Cluster,
     locals_: jnp.ndarray,
-    cls: jnp.ndarray,
     pod: PodSpec,
 ) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
     """Candidate lists for Balanced-Pandas-Pod routing.
 
-    locals_: int32 [..., n_rep]; cls: int32 [..., M] locality classes.
-    Returns (cand_idx, cand_cls, valid), each [..., C] with
-    C = n_rep + d_rack + d_remote, ordered [locals | rack draws | remote
-    draws] so that first-index tie-breaking prefers faster classes — the
-    ordering the paper's ArgMin notation implies.
+    locals_: int32 [..., n_rep], distinct servers per task.  Returns
+    (cand_idx, cand_cls, valid), each [..., C] with C = n_rep + d_rack +
+    d_remote, ordered [locals | rack draws | remote draws] so that
+    first-index tie-breaking prefers faster classes — the ordering the
+    paper's ArgMin notation implies.  The draws equal
+    ``masked_draws(k, locality_class(cluster, locals_) == RACK / REMOTE, d)``
+    for the two halves of ``key``, computed from the sorted triple alone.
     """
     k_rack, k_rem = jax.random.split(key)
     n_rep = locals_.shape[-1]
-    rack_idx, rack_ok = masked_draws(k_rack, cls == RACK, pod.d_rack)
-    rem_idx, rem_ok = masked_draws(k_rem, cls == REMOTE, pod.d_remote)
+    R, M = cluster.rack_size, cluster.M
+    srt = jnp.sort(locals_, axis=-1)
+    rack = srt // R                                   # ascending
+    first = jnp.concatenate(                          # first of its rack
+        [jnp.ones_like(rack[..., :1], bool),
+         rack[..., 1:] != rack[..., :-1]], axis=-1)
+    # distinct racks before each replica's own, unrolled: even over three
+    # lanes a cumsum lowers to a reduce-window of its own on the TPU
+    ranks = [jnp.zeros_like(rack[..., 0])]
+    for i in range(1, n_rep):
+        ranks.append(ranks[-1] + first[..., i].astype(rack.dtype))
+    rank = jnp.stack(ranks, axis=-1)
+    n_racks = rank[..., -1] + 1
+
+    def rack_skip(u):
+        # u-th non-replica position of the racks laid end to end, then the
+        # rank-th distinct rack's server at that offset
+        pos = rank * R + srt % R                      # ascending, distinct
+        for i in range(n_rep):
+            u = u + (u >= pos[..., i:i + 1])
+        j = u // R
+        base = sum(jnp.where(first[..., i:i + 1] & (rank[..., i:i + 1] == j),
+                             rack[..., i:i + 1], 0) for i in range(n_rep))
+        return base * R + u % R
+
+    def remote_skip(u):
+        # step over each distinct replica rack, lowest first
+        for i in range(n_rep):
+            u = u + jnp.where(first[..., i:i + 1]
+                              & (u >= rack[..., i:i + 1] * R), R, 0)
+        return u
+
+    rack_idx, rack_ok = _interval_draws(k_rack, n_racks * R - n_rep,
+                                        pod.d_rack, rack_skip, M)
+    rem_idx, rem_ok = _interval_draws(k_rem, M - n_racks * R,
+                                      pod.d_remote, remote_skip, M)
     cand_idx = jnp.concatenate([locals_, rack_idx, rem_idx], axis=-1)
     shp = locals_.shape[:-1]
     cand_cls = jnp.concatenate([

@@ -239,8 +239,15 @@ def _arrival_batch(key, cluster, scen, lam_t, a_max, need_cls: bool, pe=0):
     n = jnp.minimum(raw, a_max)
     mask = jnp.arange(a_max) < n
     locals_ = sample_locals_scenario(k_loc, cluster, scen, a_max, pe=pe)
-    cls = locality_class(cluster, locals_) if need_cls else None
+    cls = _class_tensor(cluster, locals_) if need_cls else None
     return mask, locals_, cls, (raw - n).astype(jnp.float32)
+
+
+def _class_tensor(cluster, locals_):
+    """``locality_class`` [..., M], counted per trace (class_tensor_count):
+    only full BP and the probe oracle read it; BP-Pod draws from intervals."""
+    _TRACE_COUNTS["class_tensor"] += 1
+    return locality_class(cluster, locals_)
 
 
 def _relation_rows(cluster: Cluster, rows: jnp.ndarray) -> jnp.ndarray:
@@ -364,6 +371,10 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
     probe) where probe = (rank_sum, regret_sum, n_decisions) telemetry
     (zeros when ``tcfg`` is None or probe collection is off).
 
+    cls_arr: the [A, M] class tensor for full BP; None for BP-Pod, whose
+    candidates come from the replica triples (the probe oracle then builds
+    the tensor itself).
+
     sequential: per-arrival plain-JAX routing, each arrival seeing the
     previous one's queues (the paper's model; random tie-breaks).
     batched: ONE fused kernels.route_commit launch — score, route, and
@@ -382,6 +393,9 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
     k_tie, k_pod, k_seq = jax.random.split(key, 3)
     collect = tcfg is not None and tcfg.probes
     probe = tlm.ZERO_PROBE
+    if collect and cls_arr is None:
+        with phase("accumulate"):
+            cls_arr = _class_tensor(cluster, locals_)
 
     if sequential:
         with phase("candidates"):
@@ -392,8 +406,7 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
             if pod is not None:
                 kc, kt = jax.random.split(kr)
                 with phase("candidates"):
-                    ci, cc, cv = pod_candidates(kc, cluster, loc_a, cls_a,
-                                                pod)
+                    ci, cc, cv = pod_candidates(kc, cluster, loc_a, pod)
             with phase("route"):
                 W = _bp_workload(Qc, inv_rates)
                 if pod is None:
@@ -410,6 +423,7 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
                                 (full < full[sel]).sum())
             return Qc, (sel, sc)
         keys = jax.random.split(k_seq, mask.shape[0])
+        # cls_arr None (BP-Pod without probes) is an empty pytree: not scanned
         Q, ys = jax.lax.scan(route_one, Q, (cls_arr, locals_, mask, keys))
         if collect:
             sel, sel_cls, chosen, best, rank = ys
@@ -435,8 +449,7 @@ def _bp_route_batch(key, cluster, Q, cls_arr, locals_, mask, inv_rates, pod,
         else:
             kc, _ = jax.random.split(k_pod)
             with phase("candidates"):
-                ci, cc, cv = pod_candidates(kc, cluster, locals_, cls_arr,
-                                            pod)
+                ci, cc, cv = pod_candidates(kc, cluster, locals_, pod)
             with phase("route"):
                 Q, _W, sel, sel_cls, _val = kernel_route_commit(
                     Q, mask, inv_rates, cand_idx=ci, cand_cls=cc,
@@ -477,7 +490,7 @@ def _bp_step(state: BPState, sums: RawSums, key, *, cluster, rates, cfg,
 
     with phase("arrivals"):
         mask, locals_, cls_arr, clipped = _arrival_batch(
-            k_arr, cluster, scen, lam_t, a_max, need_cls=True,
+            k_arr, cluster, scen, lam_t, a_max, need_cls=pod is None,
             pe=placement_epoch_at(scen, t))
     Q, sel, sel_cls, probe = _bp_route_batch(
         k_route, cluster, Q, cls_arr, locals_, mask, inv_rate_m, pod,
@@ -885,12 +898,18 @@ def _pod_for(algo: str, pod: Optional[PodSpec]) -> Optional[PodSpec]:
 # a_max) is guarded by a regression test asserting this stays at 1 across
 # the whole registry (tests/test_scenarios.py).
 
-_TRACE_COUNTS: dict = {"_run": 0}
+_TRACE_COUNTS: dict = {"_run": 0, "class_tensor": 0}
 
 
 def trace_count() -> int:
     """Number of times the jit'd simulator step has been (re)traced."""
     return _TRACE_COUNTS["_run"]
+
+
+def class_tensor_count() -> int:
+    """[A, M] class tensors the last trace of ``_run`` built per slot:
+    1 for full BP (or BP-Pod with probe telemetry), 0 for BP-Pod, SQ, FCFS."""
+    return _TRACE_COUNTS["class_tensor"]
 
 
 def reset_trace_count() -> None:
@@ -933,6 +952,7 @@ def _run(key, lam, scen: ScenarioData, *, algo: str, cluster: Cluster,
          rates: Rates, cfg: SimConfig, pod: Optional[PodSpec], a_max: int,
          homo_rates: bool = False, tcfg=None):
     _TRACE_COUNTS["_run"] += 1        # executes only on a jit cache miss
+    _TRACE_COUNTS["class_tensor"] = 0
     half2_from = cfg.warmup + (cfg.T - cfg.warmup) // 2
     family = _family(algo)
 

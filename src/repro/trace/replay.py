@@ -9,9 +9,11 @@ run* out of the slot loop:
                      catalog row is resolved (compile.arrival_rows), and
                      the per-slot arrival tensors ([T, A, 3] replica
                      triples + validity mask) are packed contiguously.
-  chunk prep (jit)   per chunk of S slots, locality classes ([S, A, M])
-                     and pod candidate lists ([S, A, C]) are computed in
-                     one vectorized shot — the slot scan then runs only
+  chunk prep (jit)   per chunk of S slots, pod candidate lists
+                     ([S, A, C], drawn from the replica triples) or, for
+                     full BP only, locality classes ([S, A, M]) are
+                     computed in one vectorized shot — the slot scan
+                     then runs only
                      service progress, local scheduling, the fused
                      route_commit launch, and the accumulators.  No
                      Poisson sampling, no categorical catalog draws, no
@@ -115,13 +117,13 @@ def _replay_chunk(state: BPState, sums: RawSums, tele, locals_c, mask_c,
 
     # vectorized chunk prep: everything per-arrival that does not depend
     # on queue state happens once, outside the slot scan
-    with phase("arrivals"):
-        cls_c = locality_class(cluster, locals_c)          # [S, A, M]
-    if not full_bp:
+    if full_bp:
+        with phase("arrivals"):
+            cls_c = locality_class(cluster, locals_c)      # [S, A, M]
+    else:
         k_cand, key = jax.random.split(key)
         with phase("candidates"):
-            ci, cc, cv = pod_candidates(k_cand, cluster, locals_c, cls_c,
-                                        pod)
+            ci, cc, cv = pod_candidates(k_cand, cluster, locals_c, pod)
             cv = cv & mask_c[..., None]
 
     def slot_step(carry, s):

@@ -427,12 +427,11 @@ def test_route_commit_wseq_replays_decision_workloads():
 
 def test_kernels_compose_as_router_pipeline():
     """classes -> pod_route -> queue_update: one routing tick end-to-end."""
-    from repro.core import Cluster, PodSpec, locality_class, pod_candidates, sample_locals
+    from repro.core import Cluster, PodSpec, pod_candidates, sample_locals
     c = Cluster(M=128, K=8)
     key = jax.random.PRNGKey(0)
     locals_ = sample_locals(key, c, 32)
-    cls = locality_class(c, locals_)
-    ci, cc, cv = pod_candidates(key, c, locals_, cls, PodSpec(2, 6))
+    ci, cc, cv = pod_candidates(key, c, locals_, PodSpec(2, 6))
     Q = jnp.zeros((c.M, 3), jnp.int32)
     W = jnp.zeros((c.M,), jnp.float32)
     for _ in range(3):

@@ -161,15 +161,15 @@ def test_zipf_placement_distribution_and_determinism():
 
 
 def test_pod_candidates_membership_under_zipf_placement():
-    """masked_draws-backed pod sampling stays class-consistent when the
-    locals come from the skewed placement law."""
+    """Interval pod sampling stays class-consistent when the locals come
+    from the skewed placement law."""
     from repro.core import PodSpec, pod_candidates
 
     scen, _ = realize(get_scenario("zipf_hotspot"), CLUSTER, RATES, 100)
     key = jax.random.PRNGKey(3)
     locals_ = sample_locals_scenario(key, CLUSTER, scen, 64)
     cls = locality_class(CLUSTER, locals_)
-    ci, cc, cv = pod_candidates(key, CLUSTER, locals_, cls, PodSpec(2, 4))
+    ci, cc, cv = pod_candidates(key, CLUSTER, locals_, PodSpec(2, 4))
     ci, cc, cv = map(np.asarray, (ci, cc, cv))
     cls_np = np.asarray(cls)
     for b in range(64):
@@ -629,7 +629,7 @@ def test_drained_empty_server_scores_inf_not_zero():
 
     # pod routing with the dead server in the candidate list
     key = jax.random.PRNGKey(1)
-    ci, cc, cv = pod_candidates(key, CLUSTER, locals_, cls, PodSpec(2, 4))
+    ci, cc, cv = pod_candidates(key, CLUSTER, locals_, PodSpec(2, 4))
     sel_p, _ = route_pod_candidates(key, W, ci, cc, cv, inv_m)
     assert int(sel_p[0]) != 0
 

@@ -10,11 +10,12 @@ here, ``chip_smoke.py`` on the chip).
 
 The last tests compile the one-device sweep at M=500 (T=8 slots, seconds
 each) with the kernel in Mosaic, as on the chip, and read its phase map
-(``telemetry.spans.op_phases``).  On the TPU, XLA emits the cumulative
-sums of the pod candidate draws as ``reduce-window`` instructions with no
-``op_name``, and the random permutation of full Balanced-Pandas as
-``sort``s: each must still land in ``candidates``, and the kernel must
-keep its name.  All described-chip compiles stay in this one file: only
+(``telemetry.spans.op_phases``).  On the TPU, XLA emits the random
+permutation of full Balanced-Pandas as ``sort``s, which must land in
+``candidates``; Balanced-Pandas-Pod draws its candidates from rack
+intervals, so its program holds no cumulative sum (``reduce-window``)
+and no [A, M] class tensor; full BP's class tensor is ``arrivals``; and
+the kernel must keep its name.  All described-chip compiles stay in this one file: only
 one process at a time may load the TPU's library.
 """
 import collections
@@ -144,7 +145,8 @@ def mosaic(one_chip):
             jax.clear_caches()
 
 
-def _compiled_sweep(sharding, algo: str, scenarios, loads) -> str:
+def _compiled_sweep(sharding, algo: str, scenarios, loads):
+    """(compiled text, arrival batch width A) of the sweep for the v5e."""
     _, scen, lam, a_max = sweep_grid(PAPER, PAPER_RATES, SWEEP_CFG, loads,
                                      scenarios=scenarios)
     keys = jax.random.split(jax.random.PRNGKey(0), 2)
@@ -154,55 +156,65 @@ def _compiled_sweep(sharding, algo: str, scenarios, loads) -> str:
     return sim._sweep_jit.lower(
         *args, algo=algo, cluster=PAPER, rates=PAPER_RATES, cfg=SWEEP_CFG,
         pod=sim._pod_for(algo, None), a_max=a_max,
-        tcfg=None).compile().as_text()
+        tcfg=None).compile().as_text(), a_max
 
 
 @pytest.fixture(scope="module", params=["balanced_pandas_pod",
                                         "balanced_pandas"])
 def compiled(request, mosaic):
-    """(algo, compiled text, phase map) of an M=500 sweep for the v5e."""
+    """(algo, compiled text, phase map, A) of an M=500 sweep for the v5e."""
     algo = request.param
     if algo == "balanced_pandas_pod":      # the m500-bppod-sweep grid
-        text = _compiled_sweep(mosaic, algo, ["uniform", "slow_rack"],
-                               [0.5, 0.9])
+        text, a_max = _compiled_sweep(mosaic, algo, ["uniform", "slow_rack"],
+                                      [0.5, 0.9])
     else:
-        text = _compiled_sweep(mosaic, algo, ["uniform"], [0.9])
-    return algo, text, op_phases(text)
+        text, a_max = _compiled_sweep(mosaic, algo, ["uniform"], [0.9])
+    return algo, text, op_phases(text), a_max
 
 
 def test_kernel_is_one_custom_call_named_route_commit(compiled):
-    _, text, phases = compiled
+    _, text, phases, _ = compiled
     calls = _mosaic_calls(text)
     assert [c.split(".")[0] for c in calls] == [KERNEL], calls
     assert phases[calls[0]] == "route"
 
 
 def test_cumsums_and_permutation_sorts_are_candidates(compiled):
-    algo, text, phases = compiled
+    algo, text, phases, _ = compiled
     instrs = top_level(text)
     windows = [i for i in instrs if i.opcode == "reduce-window"]
     shuffles = [i for i in instrs if i.opcode == "sort"
                 and "_shuffle" in i.op_name]
     if algo == "balanced_pandas_pod":
-        assert windows               # masked_draws' cumulative sums
+        assert windows == []         # interval draws: no cumulative sum
     else:
         assert shuffles              # the tie priority's permutation
     for i in windows + shuffles:
         assert phases.get(i.name) == "candidates", i
 
 
+def _result_shape(text: str, ins) -> str:
+    """The result shape of a top-level instruction, as the text gives it."""
+    m = re.search(rf"%{re.escape(ins.name)} = (.*?) {ins.opcode}\(", text)
+    return m.group(1)
+
+
 def test_class_tensor_is_arrivals(compiled):
-    _, text, phases = compiled
+    """Full BP's [A, M] class tensor is built in ``arrivals``; BP-Pod's
+    program builds none."""
+    algo, text, phases, a_max = compiled
     classes = [i for i in top_level(text)
-               if "slot.arrivals" in i.op_name
-               and i.name.startswith("iota_reduce_fusion")]
-    assert classes
-    for i in classes:
-        assert phases[i.name] == "arrivals"
+               if re.search(rf"\[[\d,]*\b{a_max},{PAPER.M}\]",
+                            _result_shape(text, i))
+               and phases.get(i.name) == "arrivals"]
+    if algo == "balanced_pandas_pod":
+        assert classes == []
+    else:
+        assert classes
 
 
 def test_no_leaf_of_the_scan_body_is_left_without_a_phase(compiled):
-    _, text, phases = compiled
+    _, text, phases, _ = compiled
     instrs = top_level(text)
     entry = instrs[0].computation
     sizes = collections.Counter(i.computation for i in instrs)
