@@ -898,12 +898,19 @@ def _pod_for(algo: str, pod: Optional[PodSpec]) -> Optional[PodSpec]:
 # a_max) is guarded by a regression test asserting this stays at 1 across
 # the whole registry (tests/test_scenarios.py).
 
-_TRACE_COUNTS: dict = {"_run": 0, "class_tensor": 0}
+_TRACE_COUNTS: dict = {"_run": 0, "class_tensor": 0, "sweep_devices": 0}
 
 
 def trace_count() -> int:
     """Number of times the jit'd simulator step has been (re)traced."""
     return _TRACE_COUNTS["_run"]
+
+
+def sweep_devices() -> int:
+    """Devices the last ``simulate_sweep`` dispatched its grid over: the
+    mesh width of the sharded path, 1 on the one-device path, 0 before
+    the first sweep."""
+    return _TRACE_COUNTS["sweep_devices"]
 
 
 def class_tensor_count() -> int:
@@ -1287,8 +1294,11 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
     Host spans (``telemetry.spans``): ``sweep.call`` around the call, with
     ``sweep.grid`` (stacking the grid), ``sweep.launch`` (keys and
     dispatch, until the program's futures return) and ``sweep.summarize``
-    inside it.  ``sweep_op_phases()`` maps the dispatched program's
-    instructions to the slot step's phases.
+    inside it; on a mesh, ``sweep.place`` inside ``sweep.launch`` puts
+    the keys (replicated) and the grid (split along the scenario axis)
+    on the devices.  ``sweep_op_phases()`` maps the dispatched program's
+    instructions to the slot step's phases, and ``sweep_devices()`` says
+    over how many devices it ran.
 
     Returns ``(names, SimResult, telemetry)`` where every SimResult leaf
     carries leading dims ``[n_scenarios, n_seeds, n_loads]`` and
@@ -1312,7 +1322,8 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
             S = lam.shape[0]
             D = min(len(devs), S)
             if D > 1:
-                from jax.sharding import Mesh
+                from jax.sharding import Mesh, NamedSharding
+                from jax.sharding import PartitionSpec as P
 
                 pad_s = (-S) % D
                 if pad_s:
@@ -1322,7 +1333,15 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
                     rep = lambda x: jnp.concatenate([x, x[-pad_s:]], axis=0)
                     scen = jax.tree_util.tree_map(rep, scen)
                     lam = rep(lam)
-                kw["mesh"] = Mesh(_np.asarray(devs[:D]), ("scen",))
+                mesh = Mesh(_np.asarray(devs[:D]), ("scen",))
+                with span("sweep.place"):
+                    # each device gets its block of scenarios and every
+                    # device the seeds' keys, so the program is compiled
+                    # for (and its phase map read from) these placements
+                    keys = jax.device_put(keys, NamedSharding(mesh, P()))
+                    lam, scen = jax.device_put(
+                        (lam, scen), NamedSharding(mesh, P("scen")))
+                kw["mesh"] = mesh
                 sums, tele = _sweep_sharded(keys, lam, scen, **kw)
                 _record_sweep(_sweep_sharded, (keys, lam, scen), kw)
                 if pad_s:
@@ -1332,6 +1351,7 @@ def simulate_sweep(algo: str, cluster: Cluster, rates: Rates, loads,
             else:
                 sums, tele = _sweep_jit(keys, lam, scen, **kw)
                 _record_sweep(_sweep_jit, (keys, lam, scen), kw)
+            _TRACE_COUNTS["sweep_devices"] = D
         with span("sweep.summarize"):
             res = summarize(sums, algo, cluster, rates, pod)
     return names, res, tele

@@ -24,6 +24,13 @@ their slot counts).  Queues buffer across segments and epochs, so demand
 and capacity both integrate over the run — the same time-averaged stance
 ``capacity_scale`` already takes for speed windows.
 
+That LP has a variable per (segment, chunk group, server): a million at
+M=500 with the canonical 4M-chunk catalog, about 95 s in HiGHS on one CPU.
+Where the servers of each rack share their rack-local and remote speeds
+(every registry scenario without a per-server straggler cohort), the
+same optimum comes from pooling those two classes per rack
+(``_rack_lp``: about 2 s at M=500); otherwise the per-server LP runs.
+
 ``capacity_edge`` is the dispatcher ``build.realize`` calls: uniform
 placement keeps the closed form bit-for-bit (fast path + the historical
 contract), skewed catalogs get the LP optimum.  Everything here is
@@ -138,6 +145,93 @@ def _locality_classes(locals_: np.ndarray, M: int, K: int) -> np.ndarray:
     return cls
 
 
+def _rack_homogeneous(segs: list, cluster: "Cluster",
+                      rates_arr: np.ndarray) -> bool:
+    """True when, in every segment, the servers of a rack share their
+    rack-local and remote speeds and no server serves a rack-local task
+    faster than a local one: the conditions under which ``_rack_lp`` is
+    exact."""
+    R = cluster.rack_size
+    for _, sp in segs:
+        by_rack = sp.reshape(cluster.K, R, 3)[:, :, 1:]
+        if np.any(by_rack != by_rack[:, :1]):
+            return False
+        if np.any(rates_arr[_LOCAL] * sp[:, _LOCAL]
+                  < rates_arr[_RACK] * sp[:, _RACK]):
+            return False
+    return True
+
+
+def _server_lp(uniq, segs, cluster, rates_arr, T):
+    """The LP of the module docstring, one variable per (segment, group,
+    server) that can serve the group: ``(rows, cols, vals)`` of its
+    constraint matrix without the lam column, the right-hand side and the
+    variables' upper bounds (lam's, at index 0, left to the caller)."""
+    G, M = uniq.shape[0], cluster.M
+    cls = _locality_classes(uniq, M, cluster.K)                  # [G, M]
+    rows, cols, vals = [], [], []
+    next_var = 1
+    midx = np.arange(M)
+    for s, (slots, sp) in enumerate(segs):
+        w = slots / float(T)
+        mu = rates_arr[cls] * sp[midx[None, :], cls]             # [G, M]
+        gi, mi = np.nonzero(mu > 0)
+        ids = next_var + np.arange(gi.size)
+        next_var += gi.size
+        # demand rows (one per group) take -(w * mu) * x; server-time
+        # rows (one per (segment, server)) take + x <= 1
+        rows += [gi, G + s * M + mi]
+        cols += [ids, ids]
+        vals += [-w * mu[gi, mi], np.ones(gi.size)]
+    b = np.concatenate([np.zeros(G), np.ones(len(segs) * M)])
+    return rows, cols, vals, b, np.ones(next_var)
+
+
+def _rack_lp(uniq, segs, cluster, rates_arr, T):
+    """``_server_lp``'s optimum from a smaller LP, exact under
+    ``_rack_homogeneous``.  Per segment, a group's local work keeps one
+    variable per replica server, but its rack-local and remote work get
+    one variable per rack: the servers of a rack are interchangeable for
+    it.  Each server's local time is at most 1, and each rack's total
+    time at most its size.  A rack's pooled time can always be handed
+    out to its servers; where a share of a group's rack-local work has
+    to land on one of the group's own replicas, that server runs it as
+    local work, at least as fast."""
+    G, n_rep = uniq.shape
+    M, K, R = cluster.M, cluster.K, cluster.rack_size
+    racks = uniq // R                                            # [G, n_rep]
+    own = np.zeros((G, K), bool)
+    own[np.arange(G)[:, None], racks] = True
+    rows, cols, vals, ub = [], [], [], [np.zeros(1)]
+    next_var = 1
+    for s, (slots, sp) in enumerate(segs):
+        w = slots / float(T)
+        srv = G + s * (M + K)                 # this segment's server rows
+        rk = srv + M                          # ... then its rack rows
+        mu = rates_arr[_LOCAL] * sp[uniq, _LOCAL]                # [G, n_rep]
+        gi, ji = np.nonzero(mu > 0)
+        ids = next_var + np.arange(gi.size)
+        next_var += gi.size
+        m = uniq[gi, ji]
+        rows += [gi, srv + m, rk + m // R]
+        cols += [ids, ids, ids]
+        vals += [-w * mu[gi, ji], np.ones(gi.size), np.ones(gi.size)]
+        ub.append(np.ones(gi.size))
+        sp_k = sp[::R]                                           # [K, 3]
+        mu_k = np.where(own, rates_arr[_RACK] * sp_k[None, :, _RACK],
+                        rates_arr[_REMOTE] * sp_k[None, :, _REMOTE])
+        gi, ki = np.nonzero(mu_k > 0)
+        ids = next_var + np.arange(gi.size)
+        next_var += gi.size
+        rows += [gi, rk + ki]
+        cols += [ids, ids]
+        vals += [-w * mu_k[gi, ki], np.ones(gi.size)]
+        ub.append(np.full(gi.size, float(R)))
+    b = np.concatenate([np.zeros(G)] + [
+        np.concatenate([np.ones(M), np.full(K, float(R))]) for _ in segs])
+    return rows, cols, vals, b, np.concatenate(ub)
+
+
 def fluid_edge(scen: ScenarioData, cluster: "Cluster", rates: "Rates",
                T: int) -> float:
     """Solve the fluid LP (module docstring) and return its optimum —
@@ -161,56 +255,31 @@ def fluid_edge(scen: ScenarioData, cluster: "Cluster", rates: "Rates",
         # an all-pad catalog is a uniform scenario in disguise
         return uniform_edge(scen, rates, T)
     pbar_g = pbar_g / total
-    G = uniq.shape[0]
-    M = cluster.M
     segs = speed_segments(scen, T)
-    S = len(segs)
-    cls = _locality_classes(uniq, M, cluster.K)                  # [G, M]
     rates_arr = np.array([rates.alpha, rates.beta, rates.gamma], np.float64)
-
-    # variables: z = [lam, x_0 .. x_{n-1}]; only (s, g, m) with mu > 0
-    rows, cols, vals = [], [], []
-    next_var = 1
-    cap_ub = 0.0                     # sum of best-class service rates: lam ub
-    midx = np.arange(M)
-    for s, (slots, sp) in enumerate(segs):
-        w = slots / float(T)
-        sp_cls = sp[midx[None, :], cls]                          # [G, M]
-        mu = rates_arr[cls] * sp_cls                             # [G, M]
-        cap_ub += w * (rates_arr[None, :, None]
-                       * sp.T[None, :, :]).max(axis=(0, 1)).sum()
-        gi, mi = np.nonzero(mu > 0)
-        n = gi.size
-        ids = next_var + np.arange(n)
-        next_var += n
-        # demand rows (one per group): -(w * mu) * x
-        rows.append(gi)
-        cols.append(ids)
-        vals.append(-w * mu[gi, mi])
-        # server-time rows (one per (segment, server)): + x <= 1
-        rows.append(G + s * M + mi)
-        cols.append(ids)
-        vals.append(np.ones(n))
+    build = (_rack_lp if _rack_homogeneous(segs, cluster, rates_arr)
+             else _server_lp)
+    rows, cols, vals, b, ub = build(uniq, segs, cluster, rates_arr, T)
+    # lam's upper bound: every server at its best class rate
+    ub[0] = max(sum(slots / float(T) * (rates_arr[None, :] * sp).max(1).sum()
+                    for slots, sp in segs), 1e-12)
+    G = uniq.shape[0]
     # lam column in every demand row: + pbar_g * lam <= served mass
     rows.append(np.arange(G))
     cols.append(np.zeros(G, np.int64))
     vals.append(pbar_g)
-    n_vars = next_var
-    n_rows = G + S * M
+    n_vars = ub.size
     A = _sparse.coo_matrix(
         (np.concatenate(vals), (np.concatenate(rows), np.concatenate(cols))),
-        shape=(n_rows, n_vars)).tocsr()
-    b = np.concatenate([np.zeros(G), np.ones(S * M)])
+        shape=(b.size, n_vars)).tocsr()
     c = np.zeros(n_vars)
     c[0] = -1.0                                  # maximize lam
-    bounds = np.ones((n_vars, 2))
-    bounds[:, 0] = 0.0
-    bounds[0, 1] = max(cap_ub, 1e-12)
+    bounds = np.stack([np.zeros(n_vars), ub], axis=1)
     res = _linprog(c, A_ub=A, b_ub=b, bounds=bounds, method="highs")
     if not res.success:  # pragma: no cover - defensive; LP is always feasible
         raise RuntimeError(
             f"capacity LP failed ({res.status}: {res.message}) — "
-            f"G={G} groups, M={M} servers, {S} segments")
+            f"G={G} groups, M={cluster.M} servers, {len(segs)} segments")
     return max(0.0, float(-res.fun))
 
 

@@ -29,7 +29,11 @@ records ``(name, parent, start, end)`` from ``time.perf_counter`` in a
 bounded in-memory buffer, with a count and a total per name.  The buffer
 is always on: a span costs microseconds, against sweep calls of seconds.
 ``spans()`` reads it, ``totals()`` the per-name sums, ``reset()`` clears
-both.
+both.  ``core.simulate_sweep`` opens ``sweep.call`` around
+``sweep.grid``, ``sweep.launch`` and ``sweep.summarize``; on a mesh,
+``sweep.launch`` holds ``sweep.place``, which puts the grid on the
+devices, and ``core.simulator.sweep_devices()`` counts the devices of
+the last dispatch.
 """
 from __future__ import annotations
 

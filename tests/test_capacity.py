@@ -23,6 +23,7 @@ import pytest
 from repro.core import Cluster, Rates, SimConfig, simulate, simulate_auto_warmup
 from repro.core.refsim import simulate_bp_ref
 from repro.scenarios import SCENARIOS, canonical_pad, compose, realize
+from repro.scenarios import capacity
 from repro.scenarios.build import ScenarioData
 from repro.scenarios.capacity import (
     capacity_edge,
@@ -80,6 +81,34 @@ def test_lp_regression_identity_uniform_catalog():
     got = fluid_edge(scen, cl, RATES, 1000)
     assert got == pytest.approx(uniform_edge(scen, RATES, 1000), rel=1e-9)
     assert got == pytest.approx(RATES.alpha * 12, rel=1e-9)
+
+
+@pytest.mark.parametrize("M,K", [(24, 4), (60, 10)])
+@pytest.mark.parametrize("parts", [
+    ("zipf_hotspot",), ("adversarial_placement",),
+    ("rack_outage", "zipf_hotspot"), ("slow_rack", "zipf_hotspot"),
+    ("network_degraded", "zipf_hotspot")])
+def test_rack_pooled_lp_equals_the_server_lp(parts, M, K, monkeypatch):
+    """Where a rack's servers share their rack-local and remote speeds,
+    the LP that pools those two classes per rack has the per-server
+    LP's optimum."""
+    cl = Cluster(M=M, K=K)
+    scen, _ = realize(compose(*parts), cl, RATES, 1000)
+    segs = capacity.speed_segments(scen, 1000)
+    rates = np.array([RATES.alpha, RATES.beta, RATES.gamma])
+    assert capacity._rack_homogeneous(segs, cl, rates)
+    pooled = fluid_edge(scen, cl, RATES, 1000)
+    monkeypatch.setattr(capacity, "_rack_homogeneous", lambda *a: False)
+    assert fluid_edge(scen, cl, RATES, 1000) == pytest.approx(pooled,
+                                                              rel=1e-9)
+
+
+def test_per_server_speeds_take_the_server_lp():
+    scen, _ = realize(compose("straggler_wave", "zipf_hotspot"), CLUSTER,
+                      RATES, 1000)
+    rates = np.array([RATES.alpha, RATES.beta, RATES.gamma])
+    assert not capacity._rack_homogeneous(
+        capacity.speed_segments(scen, 1000), CLUSTER, rates)
 
 
 def test_lp_segments_and_demand_helpers():

@@ -186,10 +186,43 @@ print("REUSE_OK")
 """
 
 
-def _run_on_two_host_devices(script: str):
+_MESH4_SCRIPT = r"""
+import numpy as np
+import jax
+assert jax.device_count() == 4, jax.devices()
+from repro.core import (Cluster, Rates, SimConfig, simulate_sweep,
+                        sweep_devices)
+from repro.core.simulator import sweep_op_phases
+from repro.telemetry.spans import reset, spans
+# the four scenarios of the m500-sweep-4chip cell at a small fleet
+cluster, rates = Cluster(M=40, K=4), Rates(0.01, 0.005, 0.002)
+cfg = SimConfig(T=400, warmup=100, route_mode="batched")
+names = ["uniform", "slow_rack", "rack_outage", "zipf_hotspot"]
+run = lambda devices: simulate_sweep(
+    "balanced_pandas_pod", cluster, rates, [0.5, 0.9], 2, cfg, seed0=11,
+    scenarios=names, devices=devices)[1]
+reset()
+sharded = run(None)
+assert sweep_devices() == 4
+assert len(spans("sweep.place")) == 1
+assert len(sharded.mean_tasks_in_system.sharding.device_set) == 4
+# the phase map is read from the sharded program just dispatched
+assert {"service", "schedule", "arrivals", "candidates", "route",
+        "accumulate"} <= set(sweep_op_phases().values())
+single = run(jax.devices()[:1])
+assert sweep_devices() == 1
+assert len(spans("sweep.place")) == 1        # one device: nothing placed
+for a, b in zip(sharded, single):
+    np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+assert np.asarray(sharded.mean_tasks_in_system).shape == (4, 2, 2)
+print("MESH4_OK")
+"""
+
+
+def _run_on_host_devices(script: str, n: int = 2):
     env = dict(os.environ)
     env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "")
-                        + " --xla_force_host_platform_device_count=2")
+                        + f" --xla_force_host_platform_device_count={n}")
     env["PYTHONPATH"] = os.pathsep.join(
         [os.path.join(os.path.dirname(__file__), "..", "src"),
          env.get("PYTHONPATH", "")])
@@ -201,7 +234,7 @@ def _run_on_two_host_devices(script: str):
 def test_sharded_sweep_reuses_its_program():
     """A second sharded sweep over the same grid compiles nothing: the
     shard_mapped program is cached like the single-device one."""
-    proc = _run_on_two_host_devices(_REUSE_SCRIPT)
+    proc = _run_on_host_devices(_REUSE_SCRIPT)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "REUSE_OK" in proc.stdout
 
@@ -210,6 +243,17 @@ def test_shard_map_matches_single_device():
     """With 2 forced host devices, the scenario axis shard_maps across
     them and the result is bit-identical to the single-device vmap —
     including the uneven (3 scenarios on 2 devices) pad-and-drop."""
-    proc = _run_on_two_host_devices(_SHARD_SCRIPT)
+    proc = _run_on_host_devices(_SHARD_SCRIPT)
     assert proc.returncode == 0, proc.stderr[-2000:]
     assert "SHARD_OK" in proc.stdout
+
+
+def test_cell_scenarios_on_four_devices_match_one_device():
+    """The m500-sweep-4chip grid's scenarios, the rack outage and the Zipf
+    catalog among them, one per device of 4: every cell is bit-identical
+    to the one-device sweep, the mesh is 4 wide, the call places its grid
+    on it once (``sweep.place``), and the sharded program has a phase
+    map."""
+    proc = _run_on_host_devices(_MESH4_SCRIPT, 4)
+    assert proc.returncode == 0, proc.stderr[-2000:]
+    assert "MESH4_OK" in proc.stdout

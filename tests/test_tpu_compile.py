@@ -9,7 +9,9 @@ lowers to.  Nothing runs, so results are checked elsewhere (test_kernels
 here, ``chip_smoke.py`` on the chip).
 
 The last tests compile the one-device sweep at M=500 (T=8 slots, seconds
-each) with the kernel in Mosaic, as on the chip, and read its phase map
+each) with the kernel in Mosaic, as on the chip, and the sweep sharded
+over the four chips of the v5e:2x2 for the m500-sweep-4chip grid, and
+read their phase maps
 (``telemetry.spans.op_phases``).  On the TPU, XLA emits the random
 permutation of full Balanced-Pandas as ``sort``s, which must land in
 ``candidates``; Balanced-Pandas-Pod draws its candidates from rack
@@ -26,7 +28,9 @@ import pytest
 
 import jax
 import jax.numpy as jnp
-from jax.sharding import SingleDeviceSharding
+import numpy as np
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import PartitionSpec as P
 
 from repro.core import Cluster, Rates, SimConfig, sweep_grid
 from repro.core import simulator as sim
@@ -45,8 +49,8 @@ PAPER_RATES = Rates(0.01, 0.005, 0.002)
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One device of a described (not attached) v5e:2x2 topology."""
+def topo():
+    """A described (not attached) v5e:2x2 topology."""
     from jax.experimental import topologies
     with pytest.MonkeyPatch.context() as mp:
         mp.setenv("TPU_LOG_DIR", "disabled")
@@ -59,8 +63,14 @@ def one_chip():
     # never be read back without a chip: keep the cache out of it
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One device of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _batch(M: int) -> int:
@@ -213,8 +223,9 @@ def test_class_tensor_is_arrivals(compiled):
         assert classes
 
 
-def test_no_leaf_of_the_scan_body_is_left_without_a_phase(compiled):
-    _, text, phases, _ = compiled
+def _unphased_scan_leaves(text: str, phases: dict) -> list:
+    """Leaves of the slot scan's body (the entry's largest loop) that the
+    phase map leaves out."""
     instrs = top_level(text)
     entry = instrs[0].computation
     sizes = collections.Counter(i.computation for i in instrs)
@@ -225,5 +236,57 @@ def test_no_leaf_of_the_scan_body_is_left_without_a_phase(compiled):
               and i.opcode not in CONTAINER_OPS
               and i.opcode not in PLUMBING_OPS]
     assert leaves
-    assert [i.name for i in leaves if phases.get(i.name) not in PHASES] \
-        == []
+    return [i.name for i in leaves if phases.get(i.name) not in PHASES]
+
+
+def test_no_leaf_of_the_scan_body_is_left_without_a_phase(compiled):
+    _, text, phases, _ = compiled
+    assert _unphased_scan_leaves(text, phases) == []
+
+
+# ---------------------------------------------------------------------------
+# the sweep sharded over the four chips (the m500-sweep-4chip grid)
+# ---------------------------------------------------------------------------
+
+MESH_SCENARIOS = ["uniform", "slow_rack", "rack_outage", "zipf_hotspot"]
+
+
+@pytest.fixture(scope="module")
+def sharded(topo, mosaic):
+    """(compiled text, phase map) of the M=500 BP-Pod sweep over the four
+    scenarios, one per chip of the described v5e:2x2, its arguments placed
+    as ``simulate_sweep`` places them."""
+    mesh = Mesh(np.asarray(topo.devices), ("scen",))
+    _, scen, lam, a_max = sweep_grid(PAPER, PAPER_RATES, SWEEP_CFG,
+                                     [0.5, 0.9], scenarios=MESH_SCENARIOS)
+    keys = jax.random.split(jax.random.PRNGKey(0), 2)
+
+    def on(spec):
+        return lambda x: jax.ShapeDtypeStruct(
+            x.shape, x.dtype, sharding=NamedSharding(mesh, spec))
+    args = ((on(P())(keys),)
+            + jax.tree_util.tree_map(on(P("scen")), (lam, scen)))
+    algo = "balanced_pandas_pod"
+    text = sim._sweep_sharded.lower(
+        *args, mesh=mesh, algo=algo, cluster=PAPER, rates=PAPER_RATES,
+        cfg=SWEEP_CFG, pod=sim._pod_for(algo, None), a_max=a_max,
+        tcfg=None).compile().as_text()
+    return text, op_phases(text)
+
+
+def test_sharded_sweep_runs_the_kernel_in_mosaic_without_collectives(
+        sharded):
+    """Each chip runs its own scenario: one Mosaic ``route_commit.N`` in
+    the ``route`` phase, and nothing crosses chips."""
+    text, phases = sharded
+    calls = _mosaic_calls(text)
+    assert [c.split(".")[0] for c in calls] == [KERNEL], calls
+    assert phases[calls[0]] == "route"
+    crossing = [i.name for i in top_level(text)
+                if i.opcode.startswith(("all-", "collective-",
+                                        "reduce-scatter"))]
+    assert crossing == []
+
+
+def test_no_leaf_of_the_sharded_scan_body_is_left_without_a_phase(sharded):
+    assert _unphased_scan_leaves(*sharded) == []
