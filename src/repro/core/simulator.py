@@ -102,6 +102,7 @@ from ..telemetry import collectors as tlm
 from ..telemetry.spans import op_phases, phase, span
 from ..scenarios.build import (
     ScenarioData,
+    catalog_levels,
     placement_epoch_at,
     realize,
     sample_locals_scenario,
@@ -238,9 +239,20 @@ def _arrival_batch(key, cluster, scen, lam_t, a_max, need_cls: bool, pe=0):
     raw = jax.random.poisson(k_n, lam_t)
     n = jnp.minimum(raw, a_max)
     mask = jnp.arange(a_max) < n
-    locals_ = sample_locals_scenario(k_loc, cluster, scen, a_max, pe=pe)
+    locals_ = _catalog_locals(k_loc, cluster, scen, a_max, pe)
     cls = _class_tensor(cluster, locals_) if need_cls else None
     return mask, locals_, cls, (raw - n).astype(jnp.float32)
+
+
+def _catalog_locals(key, cluster, scen, batch, pe):
+    """``sample_locals_scenario``, counted per trace (catalog_draw_count):
+    a draw whose search compares every task with every catalog row (a
+    one-level search: the catalog fits one row of pivots) counts one."""
+    locals_ = sample_locals_scenario(key, cluster, scen, batch, pe=pe)
+    if scen.chunk_locals is not None and \
+            catalog_levels(scen.epoch_cdf.shape[-1]) == 1:
+        _TRACE_COUNTS["catalog_draw"] += 1
+    return locals_
 
 
 def _class_tensor(cluster, locals_):
@@ -818,8 +830,7 @@ def _fcfs_step(state: FCFSState, sums: RawSums, key, *, cluster, rates, cfg,
     # everything else, so sampling it at dequeue time is distributionally
     # identical.
     pe = placement_epoch_at(scen, t)
-    locals_g = sample_locals_scenario(k_loc, cluster, scen, G,
-                                      pe=pe)  # [G, n_rep]
+    locals_g = _catalog_locals(k_loc, cluster, scen, G, pe)  # [G, n_rep]
     rack_of = cluster.rack_of
     is_local = (locals_g == rows[:, None]).any(axis=1)
     in_rack = (rack_of[locals_g] == rack_of[rows][:, None]).any(axis=1)
@@ -898,7 +909,8 @@ def _pod_for(algo: str, pod: Optional[PodSpec]) -> Optional[PodSpec]:
 # a_max) is guarded by a regression test asserting this stays at 1 across
 # the whole registry (tests/test_scenarios.py).
 
-_TRACE_COUNTS: dict = {"_run": 0, "class_tensor": 0, "sweep_devices": 0}
+_TRACE_COUNTS: dict = {"_run": 0, "class_tensor": 0, "catalog_draw": 0,
+                       "sweep_devices": 0}
 
 
 def trace_count() -> int:
@@ -917,6 +929,14 @@ def class_tensor_count() -> int:
     """[A, M] class tensors the last trace of ``_run`` built per slot:
     1 for full BP (or BP-Pod with probe telemetry), 0 for BP-Pod, SQ, FCFS."""
     return _TRACE_COUNTS["class_tensor"]
+
+
+def catalog_draw_count() -> int:
+    """Catalog draws that compare every task with every catalog row (the
+    O(B*C) pass) the last trace of ``_run`` built per slot: 0 for any
+    catalog of more than ``SEARCH_FANOUT`` rows, whose chunk is found by a
+    k-ary inverse-CDF search."""
+    return _TRACE_COUNTS["catalog_draw"]
 
 
 def reset_trace_count() -> None:
@@ -960,6 +980,7 @@ def _run(key, lam, scen: ScenarioData, *, algo: str, cluster: Cluster,
          homo_rates: bool = False, tcfg=None):
     _TRACE_COUNTS["_run"] += 1        # executes only on a jit cache miss
     _TRACE_COUNTS["class_tensor"] = 0
+    _TRACE_COUNTS["catalog_draw"] = 0
     half2_from = cfg.warmup + (cfg.T - cfg.warmup) // 2
     family = _family(algo)
 

@@ -87,6 +87,11 @@ class ScenarioData(NamedTuple):
                   chunk_logits, pad rows get ~ -inf.
     placement_epoch  [T] int32 slot -> churn-epoch index into epoch_logits
                   (zeros for single-epoch placements), or None.
+    epoch_cdf     [P, C] float32 cumulative popularity of each row of
+                  epoch_logits (of chunk_logits as one row where a
+                  placement has no epochs), built on the host by
+                  ``catalog_cdf``: the table ``sample_locals_scenario``
+                  searches by inverse CDF.  None without a catalog.
     """
 
     lam_shape: jnp.ndarray
@@ -101,6 +106,7 @@ class ScenarioData(NamedTuple):
     size_sigma: Optional[jnp.ndarray] = None
     epoch_logits: Optional[jnp.ndarray] = None
     placement_epoch: Optional[jnp.ndarray] = None
+    epoch_cdf: Optional[jnp.ndarray] = None
 
     @property
     def M(self) -> int:
@@ -379,31 +385,93 @@ def placement_epoch_at(scen: Optional[ScenarioData], t):
     return scen.placement_epoch[t]
 
 
+def catalog_cdf(logits) -> jnp.ndarray:
+    """[..., C] float32 cumulative popularity of the categorical law
+    ``softmax(logits)`` along the last axis: the table
+    ``sample_locals_scenario`` searches with one uniform per task.
+
+    Host-side, in float64 from logits shifted by their row maximum (the law
+    is shift-invariant), then normalised: the last entry with positive mass,
+    and every entry after it, is exactly 1.0, so a uniform in [0, 1) always
+    lands inside the catalog, and an entry with no mass repeats the one
+    before it and is never drawn.  A row whose logits are all equal (the
+    uniform scenarios' dummy catalog, unused epoch rows) gets the uniform
+    law, as ``jax.random.categorical`` would give it."""
+    lg = np.asarray(logits, np.float64)
+    cdf = np.cumsum(np.exp(lg - lg.max(axis=-1, keepdims=True)), axis=-1)
+    return jnp.asarray((cdf / cdf[..., -1:]).astype(np.float32))
+
+
+# pivots per level of the catalog search: the 128 lanes of a TPU vector
+# register
+SEARCH_FANOUT = 128
+
+
+def catalog_levels(C: int) -> int:
+    """Levels of the k-ary catalog search over C rows (k = SEARCH_FANOUT):
+    ceil(log_k C), at least 1.  One level compares every task with every
+    row."""
+    levels = 1
+    while SEARCH_FANOUT ** levels < C:
+        levels += 1
+    return levels
+
+
+def catalog_index(cdf: jnp.ndarray, u: jnp.ndarray) -> jnp.ndarray:
+    """The row of the sorted cumulative table ``cdf`` [C] each uniform of
+    ``u`` [B] falls in: the count of entries <= u, as
+    ``jnp.searchsorted(cdf, u, side="right")`` gives it.
+
+    A k-ary search (k = SEARCH_FANOUT): each of ``catalog_levels(C)``
+    levels gathers one row of k pivots per task (the last entry under
+    each of k subtrees) and counts those <= u, which is the next base-k
+    digit of the answer.  O(k log_k C) comparisons a task in a few
+    dependent steps, where a binary search takes log2 C steps of one
+    latency-bound gather each.  Pivots past the end read 1.0, which no
+    uniform in [0, 1) reaches."""
+    k, C = SEARCH_FANOUT, cdf.shape[-1]
+    levels = catalog_levels(C)
+    idx = jnp.zeros(u.shape, jnp.int32)
+    for level in range(levels):
+        s = k ** (levels - 1 - level)          # catalog rows per subtree
+        rows = -(-C // (s * k))
+        piv = cdf[s - 1::s]
+        table = jnp.pad(piv, (0, rows * k - piv.shape[0]),
+                        constant_values=1.0).reshape(rows, k)
+        idx = idx * k + (table[idx] <= u[:, None]).sum(-1, dtype=jnp.int32)
+    return idx
+
+
 def sample_locals_scenario(key: jax.Array, cluster: "Cluster",
                            scen: ScenarioData, batch: int,
                            pe=0) -> jnp.ndarray:
     """Replica triples for ``batch`` tasks under the scenario's placement.
 
-    Uniform placement defers to core.cluster.sample_locals; Zipf placement
-    draws a chunk from the popularity law and returns its fixed triple.
-    ``pe`` (scalar, may be traced — see placement_epoch_at) selects the
-    active churn epoch's conditional popularity row when the scenario
-    carries ``epoch_logits``; single-epoch placements use the global law.
-    Canonical (padded) realizations carry ``placement_on`` and select
-    between the two laws by data — both draws are computed and a scalar
-    jnp.where picks one, so uniform and skewed scenarios share one trace."""
+    Uniform placement defers to core.cluster.sample_locals.  A catalog
+    placement draws each task's chunk by inverse CDF (one uniform, searched
+    in the popularity table ``epoch_cdf`` by ``catalog_index``: O(log C)
+    per task) and returns the chunk's fixed triple.  ``pe`` (scalar, may
+    be traced — see placement_epoch_at) selects the active churn epoch's
+    conditional popularity row; single-epoch placements carry one row.
+    The chunk draws from the first half of ``split(key)``; canonical
+    (padded) realizations carry ``placement_on`` and also draw the uniform
+    law from the second half, and a scalar jnp.where picks one by data, so
+    uniform and skewed scenarios share one trace."""
     from ..core.cluster import sample_locals
 
     if scen.chunk_locals is None:
         return sample_locals(key, cluster, batch)
-    logits = (scen.epoch_logits[pe] if scen.epoch_logits is not None
-              else scen.chunk_logits)
-    if scen.placement_on is None:
-        cidx = jax.random.categorical(key, logits, shape=(batch,))
-        return scen.chunk_locals[cidx]
+    if scen.epoch_cdf is None:
+        raise ValueError("a catalog placement draws from epoch_cdf: build "
+                         "the ScenarioData with realize(), or set epoch_cdf "
+                         "to catalog_cdf of its logits")
     k_cat, k_uni = jax.random.split(key)
-    cidx = jax.random.categorical(k_cat, logits, shape=(batch,))
-    skewed = scen.chunk_locals[cidx]
+    cdf = scen.epoch_cdf[pe]
+    u = jax.random.uniform(k_cat, (batch,))
+    cidx = catalog_index(cdf, u)
+    skewed = scen.chunk_locals[jnp.minimum(cidx, cdf.shape[-1] - 1)]
+    if scen.placement_on is None:
+        return skewed
     uniform = sample_locals(k_uni, cluster, batch)
     return jnp.where(scen.placement_on > 0, skewed, uniform)
 
@@ -463,8 +531,8 @@ def stack_scenarios(scenarios, cluster: "Cluster", rates: "Rates", T: int,
 # ---------------------------------------------------------------------------
 
 
-_PAD_LOGIT = -1e30  # effectively -inf popularity: pad chunks are never drawn
-#                     (finite so categorical's gumbel arithmetic stays NaN-free)
+_PAD_LOGIT = -1e30  # effectively -inf popularity: pad chunks get no mass in
+#                     catalog_cdf (finite, so an all-pad row shifts to zeros)
 
 
 def _pad_placement(chunk_logits, chunk_locals, epoch_logits,
@@ -474,11 +542,11 @@ def _pad_placement(chunk_logits, chunk_locals, epoch_logits,
 
     Uniform scenarios get a dummy catalog (never drawn: placement_on = 0);
     skewed ones are padded with _PAD_LOGIT rows.  Pad triples are the first
-    n_replicas server ids — valid, but selected with probability ~0.
+    n_replicas server ids — valid, but they carry no mass in epoch_cdf.
     epoch_logits is always emitted canonically: single-epoch placements
-    mirror the global law in row 0 (identical values, so canonical draws
-    are bit-identical to the pre-epoch behavior); unused epoch rows are
-    all-_PAD_LOGIT and never indexed by placement_epoch."""
+    mirror the global law in row 0 (identical values, so the draw reads
+    the same law); unused epoch rows are all-_PAD_LOGIT and never indexed
+    by placement_epoch."""
     n_rep = cluster.n_replicas
     dummy_row = np.arange(n_rep, dtype=np.int32)[None, :]
     if chunk_logits is None:
@@ -557,6 +625,10 @@ def realize(scenario: Scenario, cluster: "Cluster", rates: "Rates",
                            cluster, pad.n_chunks, pad.n_epochs)
         if placement_epoch is None:
             placement_epoch = jnp.zeros(T, jnp.int32)
+    epoch_cdf = None
+    if chunk_logits is not None:
+        epoch_cdf = catalog_cdf(epoch_logits if epoch_logits is not None
+                                else np.asarray(chunk_logits)[None])
     scen = ScenarioData(
         lam_shape=jnp.asarray(lam_shape),
         base_speed=jnp.asarray(base),
@@ -570,6 +642,7 @@ def realize(scenario: Scenario, cluster: "Cluster", rates: "Rates",
         size_sigma=jnp.float32(sigma),
         epoch_logits=epoch_logits,
         placement_epoch=placement_epoch,
+        epoch_cdf=epoch_cdf,
     )
     # placement-aware capacity edge: uniform placement keeps the closed
     # form bit-for-bit; skewed catalogs get the fluid-LP optimum (local

@@ -767,3 +767,172 @@ def test_resolve_a_max_sizes_from_peak_intensity():
         scen, _ = realize(spec, cluster, RATES, cfg.T)
         peaks.append(float(np.max(np.asarray(scen.lam_shape))))
     assert am >= cfg.resolve_a_max(lam_cap, max(peaks))
+
+
+# ---------------------------------------------------------------------------
+# the catalog draw: inverse CDF over epoch_cdf
+# ---------------------------------------------------------------------------
+
+CAT_CLUSTER = Cluster(M=40, K=4)
+
+
+def _index_catalog(scen):
+    """``scen`` with row c's triple replaced by (c, c, c): a draw then
+    returns the drawn chunk's index itself."""
+    C = scen.chunk_locals.shape[0]
+    rows = jnp.repeat(jnp.arange(C, dtype=jnp.int32)[:, None],
+                      CAT_CLUSTER.n_replicas, axis=1)
+    return scen._replace(chunk_locals=rows)
+
+
+def _drawn_indices(scen, n, key=7, pe=0):
+    loc = sample_locals_scenario(jax.random.PRNGKey(key), CAT_CLUSTER,
+                                 _index_catalog(scen), n, pe=pe)
+    return np.asarray(loc)[:, 0]
+
+
+def _assert_follows(idx, probs, n_sigma=5.0):
+    """Every chunk's frequency within n_sigma binomial deviations of its
+    probability (plus float32's resolution of the table)."""
+    n = idx.shape[0]
+    freq = np.bincount(idx, minlength=probs.shape[0]) / n
+    tol = n_sigma * np.sqrt(probs * (1 - probs) / n) + 1e-6
+    bad = np.nonzero(np.abs(freq - probs) > tol)[0]
+    assert bad.size == 0, [(int(c), freq[c], probs[c]) for c in bad[:5]]
+
+
+@pytest.mark.parametrize("padded", [False, True])
+def test_uniform_scenario_draws_equal_sample_locals_on_the_second_key(
+        padded):
+    """A uniform scenario's triples are core.cluster.sample_locals on the
+    second half of the key's split (canonical) or on the key itself
+    (unpadded), bit for bit, whatever the catalog table holds."""
+    from repro.core.cluster import sample_locals
+    pad = canonical_pad(CAT_CLUSTER) if padded else None
+    scen, _ = realize(get_scenario("uniform"), CAT_CLUSTER, RATES, 50,
+                      pad=pad)
+    for seed in (0, 1, 2 ** 31 + 5):
+        for batch in (1, 22, 90):
+            key = jax.random.PRNGKey(seed)
+            got = sample_locals_scenario(key, CAT_CLUSTER, scen, batch,
+                                         pe=jnp.int32(0))
+            want = sample_locals(jax.random.split(key)[1] if padded
+                                 else key, CAT_CLUSTER, batch)
+            np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+
+
+@pytest.mark.parametrize("padded", [False, True])
+def test_zipf_catalog_draw_follows_the_popularity_law(padded):
+    pad = canonical_pad(CAT_CLUSTER) if padded else None
+    scen, _ = realize(get_scenario("zipf_hotspot"), CAT_CLUSTER, RATES, 50,
+                      pad=pad)
+    probs = np.exp(np.asarray(scen.chunk_logits, np.float64))
+    assert probs.sum() == pytest.approx(1.0, abs=1e-5)
+    _assert_follows(_drawn_indices(scen, 200_000), probs)
+
+
+def test_churn_epoch_row_draw_follows_its_conditional_law():
+    """A trace-backed placement: each epoch row of epoch_cdf is that
+    epoch's conditional popularity, and a draw at epoch e stays on e's
+    rows."""
+    from repro.trace import scenario_from_trace, synth_trace
+    log = synth_trace(name="churn", n_tasks=2000, seed=3, churn_t=(0.5,),
+                      n_tenants=2, n_chunks=64)
+    scen, _ = realize(scenario_from_trace(log, seed=1), CAT_CLUSTER, RATES,
+                      64, pad=canonical_pad(CAT_CLUSTER))
+    elog = np.asarray(scen.epoch_logits, np.float64)
+    assert elog.shape[0] >= 2
+    for e in (0, 1):
+        probs = np.exp(elog[e] - elog[e].max())
+        probs /= probs.sum()
+        idx = _drawn_indices(scen, 120_000, key=11 + e, pe=jnp.int32(e))
+        _assert_follows(idx, probs)
+        assert probs[idx].min() > 1e-9          # only the epoch's own rows
+
+
+def test_catalog_rows_without_mass_are_never_drawn():
+    """Pad rows and _PAD_LOGIT rows carry no mass; an all-_PAD_LOGIT row
+    (the uniform scenarios' dummy catalog) still draws in range."""
+    from repro.scenarios.build import _PAD_LOGIT, catalog_cdf
+    pad = canonical_pad(CAT_CLUSTER)
+    raw, _ = realize(get_scenario("zipf_hotspot"), CAT_CLUSTER, RATES, 50)
+    C_real = raw.chunk_logits.shape[0]
+    can, _ = realize(get_scenario("zipf_hotspot"), CAT_CLUSTER, RATES, 50,
+                     pad=pad)
+    C = can.chunk_logits.shape[0]
+    cdf = np.asarray(can.epoch_cdf)
+    assert cdf.dtype == np.float32 and cdf.shape[1] == C
+    assert np.all(np.diff(cdf[0]) >= 0) and np.all(cdf[0, C_real - 1:] == 1.0)
+
+    # mass on every third row only, the rest _PAD_LOGIT
+    logits = np.full(C, _PAD_LOGIT, np.float32)
+    live = np.arange(0, C, 3)
+    logits[live] = np.log(np.linspace(1.0, 2.0, live.size))
+    gappy = can._replace(epoch_cdf=catalog_cdf(logits[None]))
+    idx = _drawn_indices(gappy, 50_000)
+    assert set(np.unique(idx)) <= set(live.tolist())
+    assert np.asarray(gappy.epoch_cdf)[0, live[-1]] == 1.0
+
+    # an all-_PAD_LOGIT row: uniform over the catalog, always in range
+    dead = can._replace(epoch_cdf=catalog_cdf(
+        np.full((1, C), _PAD_LOGIT, np.float32)))
+    idx = _drawn_indices(dead, 50_000)
+    assert idx.min() >= 0 and idx.max() < C
+    _assert_follows(idx, np.full(C, 1.0 / C))
+
+    # the uniform scenario's dummy catalog and its unused epoch rows
+    uni, _ = realize(get_scenario("uniform"), CAT_CLUSTER, RATES, 50,
+                     pad=pad)
+    for e in range(uni.epoch_cdf.shape[0]):
+        idx = _drawn_indices(uni._replace(placement_on=None), 4_000,
+                             pe=jnp.int32(e))
+        assert idx.min() >= 0 and idx.max() < C
+    # a hand-built catalog without its table is refused, not misdrawn
+    with pytest.raises(ValueError, match="epoch_cdf"):
+        sample_locals_scenario(jax.random.PRNGKey(0), CAT_CLUSTER,
+                               can._replace(epoch_cdf=None), 4)
+
+
+@pytest.mark.parametrize("C", [1, 2, 127, 128, 129, 2000, 16384, 16385,
+                               20000])
+@pytest.mark.parametrize("fanout", [2, 3, 128])
+def test_catalog_index_equals_searchsorted(monkeypatch, C, fanout):
+    """The k-ary search finds the row jnp.searchsorted finds, at uniforms
+    drawn at random and at every table entry and the float just below
+    it, with rows of no mass and a heavy row among them."""
+    from repro.scenarios import build
+    monkeypatch.setattr(build, "SEARCH_FANOUT", fanout)
+    p = np.random.default_rng(C).random(C)
+    p[1::3] = 0.0
+    p[C // 2] += p.sum()
+    cdf = build.catalog_cdf(np.where(p > 0, np.log(np.where(p > 0, p, 1.0)),
+                                     build._PAD_LOGIT))
+    top = np.float32(1) - np.float32(2 ** -24)
+    u = jnp.clip(jnp.concatenate([
+        jax.random.uniform(jax.random.PRNGKey(C), (4000,)), cdf,
+        jnp.nextafter(cdf, 0.0)]), 0.0, top)
+    np.testing.assert_array_equal(
+        np.asarray(build.catalog_index(cdf, u)),
+        np.asarray(jnp.searchsorted(cdf, u, side="right")))
+
+
+def test_sweep_builds_no_catalog_wide_draw(monkeypatch):
+    """A BP-Pod sweep over uniform and zipf_hotspot traces no O(B*C)
+    catalog draw; a one-level search, which compares every task with
+    every row, counts one."""
+    from repro.core import catalog_draw_count, simulate_sweep
+    from repro.scenarios import build
+    grid = dict(loads=(0.5, 0.9), n_seeds=2, seed0=3,
+                scenarios=["uniform", "zipf_hotspot"])
+    names, res, _ = simulate_sweep(
+        "balanced_pandas_pod", CAT_CLUSTER, RATES,
+        cfg=SimConfig(T=120, warmup=40, route_mode="batched"),
+        pod=PodSpec(2, 6), **grid)
+    assert catalog_draw_count() == 0
+    assert np.all(np.isfinite(np.asarray(res.mean_tasks_in_system)))
+    monkeypatch.setattr(build, "SEARCH_FANOUT",
+                        canonical_pad(CAT_CLUSTER).n_chunks)
+    simulate_sweep("balanced_pandas_pod", CAT_CLUSTER, RATES,
+                   cfg=SimConfig(T=121, warmup=40, route_mode="batched"),
+                   pod=PodSpec(2, 6), **grid)
+    assert catalog_draw_count() == 1

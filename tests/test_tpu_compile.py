@@ -16,7 +16,8 @@ read their phase maps
 permutation of full Balanced-Pandas as ``sort``s, which must land in
 ``candidates``; Balanced-Pandas-Pod draws its candidates from rack
 intervals, so its program holds no cumulative sum (``reduce-window``)
-and no [A, M] class tensor; full BP's class tensor is ``arrivals``; and
+and no [A, M] class tensor; full BP's class tensor is ``arrivals``; no
+program holds an [A, C] tensor over the chunk catalog; and
 the kernel must keep its name.  All described-chip compiles stay in this one file: only
 one process at a time may load the TPU's library.
 """
@@ -36,6 +37,7 @@ from repro.core import Cluster, Rates, SimConfig, sweep_grid
 from repro.core import simulator as sim
 from repro.core.simulator import BP_POD_DEFAULT
 from repro.kernels import route_commit
+from repro.scenarios import canonical_pad
 from repro.telemetry.spans import (
     CONTAINER_OPS,
     KERNEL,
@@ -221,6 +223,15 @@ def test_class_tensor_is_arrivals(compiled):
         assert classes == []
     else:
         assert classes
+
+
+def test_catalog_draw_builds_no_arrival_by_catalog_tensor(compiled):
+    """Each task's chunk is found by inverse CDF: no [A, C] tensor over
+    the canonical catalog (the Gumbel argmax of a categorical draw built
+    one) anywhere in the program."""
+    _, text, _, a_max = compiled
+    C = canonical_pad(PAPER).n_chunks
+    assert not re.search(rf"\[[\d,]*\b{a_max},{C}\]", text)
 
 
 def _unphased_scan_leaves(text: str, phases: dict) -> list:
